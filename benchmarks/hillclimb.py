@@ -38,7 +38,9 @@ def run_variant(arch: str, shape: str, mesh: str, extra_flags, timeout=3600):
         out = f.name
     cmd = [sys.executable, "-m", "repro.launch.dryrun", "--arch", arch,
            "--shape", shape, "--mesh", mesh, "--out", out] + list(extra_flags)
+    # a CPU-only placeholder pool: never takes an accelerator
     env = {**os.environ,
+           "JAX_PLATFORMS": "cpu",
            "XLA_FLAGS": "--xla_force_host_platform_device_count=512",
            "PYTHONPATH": os.path.join(os.path.dirname(__file__), "..",
                                       "src")}

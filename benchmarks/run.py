@@ -22,9 +22,7 @@ def main() -> None:
                          "measured,planner,overlap,elastic,ft,trace")
     args = ap.parse_args()
 
-    from benchmarks import tables
     from benchmarks.common import ART, emit
-    from benchmarks.roofline_fit import roofline_fit
 
     seeds = 3 if args.quick else 10
     small = 2 if args.quick else 3
@@ -32,7 +30,8 @@ def main() -> None:
 
     def _pool_subprocess(cmd, see):
         # subprocess: these entry points must force their device pool
-        # before jax initializes, which this process already did
+        # before jax initializes. They all run before this process
+        # touches jax (see ``jobs``), so none is locked out of a device.
         import subprocess
         import sys
         r = subprocess.run([sys.executable, "-m"] + cmd,
@@ -83,22 +82,33 @@ def main() -> None:
             raise RuntimeError(r.stderr[-2000:])
         return {"see": "tools/ft_smoke.py"}
 
+    def tables():
+        # imported only here: these jobs run jax in this process, so
+        # they come after every job that starts a child process
+        from benchmarks import tables
+        return tables
+
+    def roofline():
+        from benchmarks.roofline_fit import roofline_fit
+        return roofline_fit()
+
+    # in execution order: child-process jobs first, in-process jobs last
     jobs = {
-        "table2": lambda: tables.table2_fit(seeds, maxiter),
-        "table3": lambda: tables.table3_fit_l2(seeds, maxiter),
-        "table4": lambda: tables.table4_reg_compare(
-            max(seeds // 2, 2), maxiter),
-        "table5": lambda: tables.table5_model_compare(seeds, maxiter),
-        "table6": lambda: tables.table6_scaling(seeds, maxiter),
-        "fig7": lambda: tables.fig7_lambda_sweep("jit", small, maxiter),
-        "fig8": lambda: tables.fig8_coeff_paths("jit", small, maxiter),
-        "roofline": roofline_fit,
         "measured": measured,
         "planner": planner,
         "overlap": overlap,
         "elastic": elastic,
         "ft": ft,
         "trace": trace,
+        "table2": lambda: tables().table2_fit(seeds, maxiter),
+        "table3": lambda: tables().table3_fit_l2(seeds, maxiter),
+        "table4": lambda: tables().table4_reg_compare(
+            max(seeds // 2, 2), maxiter),
+        "table5": lambda: tables().table5_model_compare(seeds, maxiter),
+        "table6": lambda: tables().table6_scaling(seeds, maxiter),
+        "fig7": lambda: tables().fig7_lambda_sweep("jit", small, maxiter),
+        "fig8": lambda: tables().fig8_coeff_paths("jit", small, maxiter),
+        "roofline": roofline,
     }
     only = [s for s in args.only.split(",") if s]
     results = {}

@@ -90,7 +90,7 @@ def _traced_steps(rec, mesh, step, state, batch, n):
         with rec.span("step", category="train", step_num=i,
                       phase="steady"):
             with rec.span("dispatch", category="train"):
-                with mesh:
+                with jax.set_mesh(mesh):
                     state, m = step(state, batch)
             with rec.span("wait", category="train"):
                 jax.block_until_ready(m["loss"])
@@ -147,7 +147,7 @@ def _overhead(mesh, step, state, batch, rounds=OVERHEAD_ROUNDS,
     def plain_block():
         t0 = time.perf_counter()
         for _ in range(block):
-            with mesh:
+            with jax.set_mesh(mesh):
                 _, m = step(state, batch)
             jax.block_until_ready(m["loss"])
         return (time.perf_counter() - t0) / block
@@ -158,7 +158,7 @@ def _overhead(mesh, step, state, batch, rounds=OVERHEAD_ROUNDS,
             with rec.span("step", category="train", step_num=i,
                           phase="steady"):
                 with rec.span("dispatch", category="train"):
-                    with mesh:
+                    with jax.set_mesh(mesh):
                         _, m = step(state, batch)
                 with rec.span("wait", category="train"):
                     jax.block_until_ready(m["loss"])
@@ -206,7 +206,7 @@ def run_point(strategy, calibration, steps=STEPS):
                          wire_bits=WIRE_BITS["none"], act_bytes=ab)
 
     # -- traced steady-state steps (warmup step first, untraced) --------
-    with mesh:
+    with jax.set_mesh(mesh):
         state, m = step(state, batch)          # compile
     jax.block_until_ready(m["loss"])
     rec = Recorder(enabled=True)

@@ -57,7 +57,7 @@ from functools import partial
 from typing import Dict, Mapping, Optional, Sequence, Tuple, Union
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AbstractMesh, Mesh, NamedSharding, PartitionSpec as P
 
 # LocalDim / tp_f / tp_probe live in repro.models.layers (which must not
 # import repro.dist.*) and are re-exported here as the canonical API the
@@ -218,20 +218,14 @@ def axis_sizes(mesh: MeshLike) -> Dict[str, int]:
     return dict(shape)
 
 
-def active_mesh() -> Optional[Mesh]:
-    """The mesh installed by an enclosing ``with mesh:`` block, if any.
+def active_mesh() -> Optional[AbstractMesh]:
+    """The mesh installed by an enclosing ``with jax.set_mesh(mesh):``.
 
-    jax 0.4.x keeps this on ``thread_resources``; returns None outside
-    any mesh context so single-device eager/jit paths stay unconstrained.
+    Returns None outside any mesh context so single-device eager/jit
+    paths stay unconstrained.
     """
-    try:
-        from jax._src import mesh as mesh_lib
-        env_mesh = mesh_lib.thread_resources.env.physical_mesh
-        if env_mesh is not None and not env_mesh.empty:
-            return env_mesh
-    except Exception:
-        pass
-    return None
+    mesh = jax.sharding.get_abstract_mesh()
+    return None if mesh.empty else mesh
 
 
 # ---------------------------------------------------------------------------

@@ -12,7 +12,14 @@ Design (TPU-native, not a CUDA port):
   * causal / sliding-window / ring-buffer-decode masking is computed from
     *position vectors* (q_pos, kv_pos) — the same mechanism the model uses
     for its ring caches — not from row indices, so one kernel serves
-    train, prefill and decode.
+    train, prefill and decode. Mosaic tiles a 1-D int32 array differently
+    from XLA, so positions enter 2-D: q_pos as a [Sq, 1] column, kv_pos
+    as a [1, Skv] row (blocks (blk_q, 1) and (1, blk_kv)), and the
+    running max / denominator are lane-replicated (blk_q, 128) scratch,
+    as in the upstream Pallas TPU flash kernel.
+  * the backward pass recomputes attention with the blockwise jnp path
+    (``models.attention.attend_blockwise``) and differentiates that: the
+    kernel is forward-only, and pallas_call has no transpose rule.
   * logit softcap (gemma2) and scale overrides are static params fused
     into the score computation.
 
@@ -30,9 +37,10 @@ import jax.experimental.pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 import jax.numpy as jnp
 
-from repro.models.attention import AttnSpec
+from repro.models.attention import AttnSpec, attend_blockwise
 
 NEG_INF = -2.3819763e38
+LANES = 128
 
 
 def _kernel(q_ref, k_ref, v_ref, qpos_ref, kvpos_ref,   # inputs
@@ -55,28 +63,29 @@ def _kernel(q_ref, k_ref, v_ref, qpos_ref, kvpos_ref,   # inputs
     if softcap:
         s = jnp.tanh(s / softcap) * softcap
 
-    qp = qpos_ref[...]                                # [bq]
-    kp = kvpos_ref[...]                               # [bk]
-    ok = jnp.broadcast_to((kp < 2 ** 30)[None, :], s.shape)  # pad sentinel
+    qp = qpos_ref[...]                                # [bq, 1]
+    kp = kvpos_ref[...]                               # [1, bk]
+    ok = jnp.broadcast_to(kp < 2 ** 30, s.shape)      # pad sentinel
     if causal:
-        ok &= kp[None, :] <= qp[:, None]
+        ok &= kp <= qp
     if window:
-        ok &= kp[None, :] > (qp[:, None] - window)
+        ok &= kp > (qp - window)
     s = jnp.where(ok, s, NEG_INF)
 
-    m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, s.max(axis=-1))
+    # m/l hold one value per row, replicated over the 128 lanes
+    m_prev = m_ref[...]                               # [bq, 128]
+    m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
     alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(s - m_new[:, None])
-    l_ref[...] = l_ref[...] * alpha + p.sum(axis=-1)
-    acc_ref[...] = (acc_ref[...] * alpha[:, None] +
+    p = jnp.exp(s - m_new[:, :1])
+    l_ref[...] = l_ref[...] * alpha + p.sum(axis=-1, keepdims=True)
+    acc_ref[...] = (acc_ref[...] * alpha[:, :1] +
                     jax.lax.dot_general(p, v, (((1,), (0,)), ((), ()))))
     m_ref[...] = m_new
 
     @pl.when(ik == n_kv_blocks - 1)
     def _emit():
         l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / l[:, :1]).astype(o_ref.dtype)
 
 
 def flash_attention(q, k, v, q_pos, kv_pos, spec: AttnSpec, *,
@@ -87,6 +96,11 @@ def flash_attention(q, k, v, q_pos, kv_pos, spec: AttnSpec, *,
     Returns [B,Sq,Hq,hd]. Sq/Skv are padded to block multiples internally
     (padded kv positions get +inf -> masked by causality).
     """
+    return _flash(q, k, v, q_pos, kv_pos, spec, block_q, block_kv, interpret)
+
+
+def _flash_forward(q, k, v, q_pos, kv_pos, spec: AttnSpec, block_q: int,
+                   block_kv: int, interpret: bool) -> jax.Array:
     B, Sq, Hq, hd = q.shape
     Skv, Hkv = k.shape[1], k.shape[2]
     G = Hq // Hkv
@@ -126,19 +140,38 @@ def flash_attention(q, k, v, q_pos, kv_pos, spec: AttnSpec, *,
             pl.BlockSpec((1, block_q, hd), lambda h, iq, ik: (h, iq, 0)),
             pl.BlockSpec((1, block_kv, hd), kv_index),
             pl.BlockSpec((1, block_kv, hd), kv_index),
-            pl.BlockSpec((block_q,), lambda h, iq, ik: (iq,)),
-            pl.BlockSpec((block_kv,), lambda h, iq, ik: (ik,)),
+            pl.BlockSpec((block_q, 1), lambda h, iq, ik: (iq, 0)),
+            pl.BlockSpec((1, block_kv), lambda h, iq, ik: (0, ik)),
         ],
         out_specs=pl.BlockSpec((1, block_q, hd),
                                lambda h, iq, ik: (h, iq, 0)),
         out_shape=jax.ShapeDtypeStruct((B * Hq, Sq_p, hd), q.dtype),
         scratch_shapes=[
-            pltpu.VMEM((block_q,), jnp.float32),        # m
-            pltpu.VMEM((block_q,), jnp.float32),        # l
+            pltpu.VMEM((block_q, LANES), jnp.float32),  # m
+            pltpu.VMEM((block_q, LANES), jnp.float32),  # l
             pltpu.VMEM((block_q, hd), jnp.float32),     # acc
         ],
         interpret=interpret,
-    )(qf, kf, vf, q_pos.astype(jnp.int32), kv_pos.astype(jnp.int32))
+    )(qf, kf, vf, q_pos.astype(jnp.int32).reshape(Sq_p, 1),
+      kv_pos.astype(jnp.int32).reshape(1, Skv_p))
 
     out = out.reshape(B, Hq, Sq_p, hd).transpose(0, 2, 1, 3)
     return out[:, :Sq]
+
+
+def _flash_fwd(q, k, v, q_pos, kv_pos, spec, block_q, block_kv, interpret):
+    out = _flash_forward(q, k, v, q_pos, kv_pos, spec, block_q, block_kv,
+                         interpret)
+    return out, (q, k, v, q_pos, kv_pos)
+
+
+def _flash_bwd(spec, block_q, block_kv, interpret, res, g):
+    q, k, v, q_pos, kv_pos = res
+    _, vjp = jax.vjp(
+        lambda q, k, v: attend_blockwise(q, k, v, q_pos, kv_pos, spec,
+                                         block=block_kv), q, k, v)
+    return (*vjp(g), None, None)
+
+
+_flash = jax.custom_vjp(_flash_forward, nondiff_argnums=(5, 6, 7, 8))
+_flash.defvjp(_flash_fwd, _flash_bwd)

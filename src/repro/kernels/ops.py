@@ -19,10 +19,7 @@ _DISABLE = os.environ.get("REPRO_DISABLE_PALLAS", "0") == "1"
 
 @functools.cache
 def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:  # pragma: no cover - defensive
-        return False
+    return jax.default_backend() == "tpu"
 
 
 def use_pallas() -> bool:

@@ -1,4 +1,6 @@
 import os
+# placeholder pool on the host CPU; never takes an accelerator
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") +
                            " --xla_force_host_platform_device_count=512")
 """Multi-pod dry-run: lower + compile every (arch × shape) cell on the
@@ -61,9 +63,8 @@ def run_cell(arch: str, shape_id: str, mesh_kind: str = "pod",
         shape = dataclasses.replace(shape, microbatches=microbatches)
     t0 = time.time()
     prog = input_specs(cfg, shape, mesh, tcfg, strategy)
-    # Mesh context manager (jax.sharding.set_mesh only exists in newer jax);
-    # maybe_constrain reads the active mesh during tracing.
-    with mesh:
+    # maybe_constrain reads the active mesh during tracing
+    with jax.set_mesh(mesh):
         jitted = jax.jit(prog.fn, in_shardings=prog.in_shardings,
                          donate_argnums=prog.donate_argnums)
         lowered = jitted.lower(*prog.args)

@@ -6,8 +6,10 @@ mesh-aware under the same strategy registry as training.
   PYTHONPATH=src python -m repro.launch.serve --arch qwen2.5-3b --reduced \
       --batch 4 --prompt-len 32 --gen 32 --strategy tp
 
-With ``--strategy`` the driver forces the host device pool (like the
-train driver), plans a (data, model) mesh, and serves *sharded*: params
+The first line of output names the platform and device kind. With
+``--strategy`` the driver plans a (data, model) mesh over the devices
+(on a CPU-only host it first forces the host device pool, like the
+train driver) and serves *sharded*: params
 follow the strategy's logical-rule PartitionSpecs, KV caches shard per
 their role (batch over data, kv-heads over model — see
 ``repro.launch.specs._cache_pspec``), and every decode step runs jit
@@ -59,6 +61,10 @@ def main(argv=None):
     import jax
     import jax.numpy as jnp
 
+    from repro.launch.mesh import describe_platform, enable_compile_cache
+    print(describe_platform(), flush=True)
+    enable_compile_cache()
+
     from repro.configs import get_config, reduced
     from repro.data import make_batch_for
     from repro.launch.mesh import make_mesh
@@ -104,7 +110,7 @@ def main(argv=None):
 
     enc_kv = None
     if cfg.is_encoder_decoder:
-        with mesh:
+        with jax.set_mesh(mesh):
             enc_out = MD.encoder_forward(params, cfg, batch["frames"])
             enc_kv = MD._stacked_cross_kv(params, cfg, enc_out)
 
@@ -137,7 +143,7 @@ def main(argv=None):
 
     t0 = time.time()
     logits = None
-    with mesh:
+    with jax.set_mesh(mesh):
         with rec.span("prefill", category="serve", batch=B, tokens=S):
             for pos in range(S):               # batched prefill-by-decode
                 logits, caches = decode(params, caches,
@@ -146,6 +152,7 @@ def main(argv=None):
             jax.block_until_ready(logits)
             t_prefill = time.time() - t0
 
+        prompt_logits = logits             # at the last prompt position
         out_tokens = []
         tok = reput_tok(jnp.argmax(logits, axis=-1)[:, None])
         t0 = time.time()
@@ -187,7 +194,8 @@ def main(argv=None):
         report["trace"] = {"dir": args.trace_dir,
                            "spans": len(rec.spans)}
     print(json.dumps(report))
-    return report
+    # arrays for in-process callers that check the output; not printed
+    return {**report, "tokens": gen, "prompt_logits": prompt_logits}
 
 
 if __name__ == "__main__":

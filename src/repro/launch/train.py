@@ -12,9 +12,11 @@ Features exercised here (the production path in miniature):
       - "gspmd": jit with logical-rule shardings; XLA inserts the
         collectives. The fallback for adafactor / indivisible batches.
     ``--mode auto`` (default) picks "sharded" whenever it can.
-  * an 8-device placeholder pool is forced on CPU hosts (before the jax
-    backend initializes), so the default invocation exercises real
-    collectives; override with --devices N or an explicit XLA_FLAGS.
+  * on a CPU-only host an 8-device placeholder pool is forced, so the
+    default invocation exercises real collectives; override with
+    --devices N or an explicit XLA_FLAGS. On an accelerator the run uses
+    the accelerator's devices and no pool is made. The first line of
+    output names the platform and device kind.
   * deterministic step-indexed data (resume-safe)
   * checkpoint/restart: atomic async checkpoints, auto-resume from latest
   * straggler detection via the fitted performance model when available
@@ -34,12 +36,25 @@ DEFAULT_POOL = 8      # placeholder pool forced on single-CPU hosts
 
 
 def _force_host_pool(n: int) -> None:
-    """Request an n-device host platform pool. Must run before the first
-    jax backend touch; a pre-existing user flag always wins."""
-    flags = os.environ.get("XLA_FLAGS", "")
-    if "xla_force_host_platform_device_count" not in flags:
-        os.environ["XLA_FLAGS"] = (
-            flags + f" --xla_force_host_platform_device_count={n}")
+    """Give the CPU backend an n-device pool when the run will be on CPU.
+
+    A no-op when an accelerator is present, and when a host device
+    count was already asked for, by XLA_FLAGS or an earlier call (the
+    first request wins). Must run before anything else in the process
+    touches jax devices.
+    """
+    import jax
+    from jax.extend.backend import clear_backends
+    if ("xla_force_host_platform_device_count" in
+            os.environ.get("XLA_FLAGS", "")
+            or jax.config.jax_num_cpu_devices > 0):
+        return
+    if os.environ.get("JAX_PLATFORMS", "") != "cpu":
+        if jax.default_backend() != "cpu":
+            return
+        # only the CPU was found: rebuild it with the pool
+        clear_backends()
+    jax.config.update("jax_num_cpu_devices", n)
 
 
 def build_parser() -> argparse.ArgumentParser:
@@ -68,8 +83,9 @@ def build_parser() -> argparse.ArgumentParser:
                     help="sharded = shard_map with measured collectives; "
                          "gspmd = jit-with-shardings; auto prefers sharded")
     ap.add_argument("--devices", type=int, default=0,
-                    help=f"host pool size to force on CPU (0 = auto: "
-                         f"{DEFAULT_POOL})")
+                    help=f"run on the first N devices (0 = all); on a "
+                         f"CPU-only host also the size of the forced pool "
+                         f"(0 = {DEFAULT_POOL})")
     ap.add_argument("--remat", default="none")
     ap.add_argument("--dtype", default="",
                     help="override model compute/param dtype (e.g. "
@@ -186,6 +202,10 @@ def main(argv=None):
     import jax
     import numpy as np
 
+    from repro.launch.mesh import describe_platform, enable_compile_cache
+    print(describe_platform(), flush=True)
+    enable_compile_cache()
+
     from repro.configs import TrainConfig, get_config, reduced
     from repro.data import make_batch_for
     from repro.launch.mesh import make_mesh
@@ -228,9 +248,10 @@ def main(argv=None):
                        checkpoint_every=args.ckpt_every,
                        checkpoint_dir=args.ckpt_dir or "/tmp/repro_ckpt")
 
-    n_dev = len(jax.devices())
+    devices = jax.devices()[:args.devices or None]
+    n_dev = len(devices)
     plan = plan_remesh(n_dev)
-    mesh = make_mesh(plan.mesh_shape, ("data", "model"))
+    mesh = make_mesh(plan.mesh_shape, ("data", "model"), devices=devices)
     decision = None
     if args.strategy == "auto":
         from repro.perf.planner import choose_strategy
@@ -402,7 +423,7 @@ def main(argv=None):
                     strategy=(None if args.recover_strategy == "auto"
                               else args.recover_strategy))
                 m = make_mesh(rplan.mesh_shape, rplan.axis_names,
-                              devices=jax.devices()[:rplan.n_devices])
+                              devices=devices[:rplan.n_devices])
                 ns = argparse.Namespace(**vars(args))
                 ns.strategy = rplan.strategy
                 p2, _ = _pick_mode(ns, tcfg, m, rplan.n_devices)
@@ -421,14 +442,11 @@ def main(argv=None):
             return {}
         from repro.dist.compression import WIRE_BITS
         from repro.perf.planner.space import model_comm_sizes
-        try:
-            pb, ab = model_comm_sizes(cfg, args.batch, args.seq)
-            return collective_bytes(
-                args.strategy, n_dev, pb,
-                wire_bits=WIRE_BITS[args.compression], act_bytes=ab,
-                axes={k: int(v) for k, v in mesh.shape.items()})
-        except Exception:
-            return {}
+        pb, ab = model_comm_sizes(cfg, args.batch, args.seq)
+        return collective_bytes(
+            args.strategy, n_dev, pb,
+            wire_bits=WIRE_BITS[args.compression], act_bytes=ab,
+            axes={k: int(v) for k, v in mesh.shape.items()})
 
     detector = StragglerDetector(tolerance=args.straggler_tol)
     monitor = StragglerMonitor(detector, metrics=obs_metrics, recorder=rec)
@@ -449,7 +467,7 @@ def main(argv=None):
             # ---- simulated device loss: re-plan, reshard, resume ----
             lost = args.fail_devices or n_dev // 2
             rec.event("failure", step=int(step), lost_devices=int(lost))
-            survivors = jax.devices()[:max(n_dev - lost, 1)]
+            survivors = devices[:max(n_dev - lost, 1)]
             prog = None
             compile_s = 0.0
             if precomp is not None:
@@ -550,7 +568,7 @@ def main(argv=None):
                                        step=step, seed=args.seed)
             t0 = time.perf_counter()
             with rec.span("dispatch", category="train"):
-                with mesh:
+                with jax.set_mesh(mesh):
                     state, metrics = step_fn(state, batch)
             with rec.span("wait", category="train"):
                 # the loss block the untraced loop already performs —
@@ -606,12 +624,21 @@ def main(argv=None):
         ckpt.wait()
 
     losses = [loss_by_step[s] for s in sorted(loss_by_step)]
+    # where the state lives: the mesh's devices and the shards of the
+    # largest parameter (one device per shard on a real mesh)
+    big = max(jax.tree.leaves(state.params), key=lambda x: x.size)
     out = {"arch": cfg.name, "steps": args.steps,
            "first_loss": losses[0] if losses else None,
            "final_loss": float(np.mean(losses[-10:])) if losses else None,
            "wall_s": round(time.time() - t_run, 1),
            "losses": losses,
+           "step_ms": [t * 1e3 for t in step_times],
            "strategy": args.strategy, "mesh": list(mesh.devices.shape),
+           "placement": {
+               "mesh_devices": mesh.device_ids.tolist(),
+               "largest_param": list(big.shape),
+               "shards": [[s.device.id, list(s.data.shape)]
+                          for s in big.addressable_shards]},
            "straggler_flags": detector.flags}
     out["supervisor"] = {"retries": sup.retries,
                          "proactive_checkpoints": sup.proactive_checkpoints}

@@ -119,7 +119,7 @@ def tp_g(axis_name: str, x: jax.Array) -> jax.Array:
 
     Closes a row-parallel product (partial per-rank sums -> full output).
     It must be this custom pair rather than a raw ``lax.psum``: under
-    ``shard_map(check_rep=False)`` the transpose of ``psum`` is ``psum``
+    ``shard_map(check_vma=False)`` the transpose of ``psum`` is ``psum``
     again, which would multiply the (replicated) output cotangent by the
     ring size on the way back. The true adjoint of "sum the partials" is
     "hand each rank the output cotangent unchanged".

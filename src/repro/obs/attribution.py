@@ -150,7 +150,6 @@ def measure_collective_terms(mesh, strategy, inp: ScheduleInputs, *,
     import time
 
     import jax
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     if clock is None:
@@ -175,9 +174,9 @@ def measure_collective_terms(mesh, strategy, inp: ScheduleInputs, *,
         x, spec = _term_operand(op, axis, ring, g["nbytes"])
         body = _collective_body(op, axis)
         out_spec = P() if op in ("all_reduce", "all_gather") else spec
-        fn = jax.jit(shard_map(body, mesh=mesh, in_specs=spec,
-                               out_specs=out_spec, check_rep=False))
-        with mesh:
+        fn = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=spec,
+                                   out_specs=out_spec, check_vma=False))
+        with jax.set_mesh(mesh):
             xd = jax.device_put(
                 x, jax.sharding.NamedSharding(mesh, spec))
             for _ in range(max(warmup, 1)):

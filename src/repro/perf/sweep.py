@@ -258,7 +258,6 @@ def make_sharded_iteration(cfg: LeNet5Config, mode: str, mesh: Mesh,
     because ``tp_f``'s backward already completed the input cotangent,
     so the mean stays exact).
     """
-    from jax.experimental.shard_map import shard_map
     from repro.models.layers import Param
 
     axes_sizes = dict(mesh.shape)
@@ -295,9 +294,9 @@ def make_sharded_iteration(cfg: LeNet5Config, mode: str, mesh: Mesh,
                                           cfg.learning_rate, 1)
         return new_params, jax.lax.pmean(loss, axis_names)
 
-    it = shard_map(body, mesh=mesh,
-                   in_specs=(entry_specs, batch_spec, P()),
-                   out_specs=(entry_specs, P()), check_rep=False)
+    it = jax.shard_map(body, mesh=mesh,
+                       in_specs=(entry_specs, batch_spec, P()),
+                       out_specs=(entry_specs, P()), check_vma=False)
     if mode == "eager":
         return it, entry_specs, batch_spec
     donate = (0,) if mode == "jit_donate" else ()

@@ -414,7 +414,6 @@ def make_sharded_train_step(cfg: ModelConfig, tcfg: TrainConfig, mesh: Mesh,
     mesh must carry at least one batch axis, and the global batch must
     divide evenly over it (``sharded_batch_ok``).
     """
-    from jax.experimental.shard_map import shard_map
 
     if tcfg.optimizer == "adafactor":
         raise NotImplementedError(
@@ -490,10 +489,10 @@ def make_sharded_train_step(cfg: ModelConfig, tcfg: TrainConfig, mesh: Mesh,
             return TrainState(new_params, new_opt, new_ef), metrics
 
     if not overlap:
-        return shard_map(body, mesh=mesh,
-                         in_specs=(state_specs, P(_batch_entry(mesh))),
-                         out_specs=(state_specs, P()),
-                         check_rep=False)
+        return jax.shard_map(body, mesh=mesh,
+                             in_specs=(state_specs, P(_batch_entry(mesh))),
+                             out_specs=(state_specs, P()),
+                             check_vma=False)
 
     plans = _overlap_plans(cfg, tcfg, mesh, p_specs)
     sizes = axis_sizes(mesh)
@@ -580,7 +579,7 @@ def make_sharded_train_step(cfg: ModelConfig, tcfg: TrainConfig, mesh: Mesh,
             metrics.update(grad_norm=gnorm, lr=lr, loss=loss)
             return TrainState(new_params, new_opt, new_ef), metrics
 
-    return shard_map(overlap_body, mesh=mesh,
-                     in_specs=(state_specs, P(_batch_entry(mesh))),
-                     out_specs=(state_specs, P()),
-                     check_rep=False)
+    return jax.shard_map(overlap_body, mesh=mesh,
+                         in_specs=(state_specs, P(_batch_entry(mesh))),
+                         out_specs=(state_specs, P()),
+                         check_vma=False)

@@ -379,7 +379,7 @@ for src in sorted(STRATEGIES):
         if i == FAIL:
             cm.save_sharded(i, state, mesh=mesh8, strategy=src,
                             specs=specs8, extra_meta={"arch": cfg.name})
-        with mesh8:
+        with jax.set_mesh(mesh8):
             state, m = fn8(state, batches[i])
         ref.append(float(m["loss"]))
     meta = cm.read_meta(FAIL)
@@ -391,7 +391,7 @@ for src in sorted(STRATEGIES):
         assert step0 == FAIL
         got = []
         for i in range(FAIL, STEPS):
-            with mesh4:
+            with jax.set_mesh(mesh4):
                 st, m = fn4(st, batches[i])
             got.append(float(m["loss"]))
         errs = [abs(a - b) for a, b in zip(got, ref[FAIL:])]

@@ -84,6 +84,28 @@ def test_flash_attention_property(B, S, Hkv, hd, causal):
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=3e-5)
 
 
+def test_flash_attention_grads_match_ref():
+    """The kernel's custom VJP (blockwise jnp recompute) gives the
+    reference's gradients — what a training step on the chip uses."""
+    B, S, Hq, Hkv, hd = 1, 96, 4, 2, 16
+    q, k, v = _qkv(B, S, S, Hq, Hkv, hd, jnp.float32)
+    pos = jnp.arange(S)
+    spec = AttnSpec(causal=True, window=40)
+    w = jax.random.normal(jax.random.fold_in(KEY, 7), (B, S, Hq, hd))
+
+    def loss(fn):
+        return lambda q, k, v: jnp.sum(fn(q, k, v) * w)
+
+    got = jax.grad(loss(lambda q, k, v: flash_attention(
+        q, k, v, pos, pos, spec, block_q=32, block_kv=32, interpret=True)),
+        argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(loss(lambda q, k, v: attention_ref(q, k, v, pos, pos,
+                                                        spec)),
+                    argnums=(0, 1, 2))(q, k, v)
+    for g, r in zip(got, want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(r), atol=2e-5)
+
+
 def test_blockwise_jnp_matches_naive():
     """The model's CPU fallback path must equal the oracle too."""
     B, S, Hq, Hkv, hd = 2, 256, 4, 2, 16
@@ -123,6 +145,36 @@ def test_ssd_scan_matches_ref(case, dtype):
                                np.asarray(yr, np.float32), atol=tol)
     np.testing.assert_allclose(np.asarray(st_final, np.float32),
                                np.asarray(str_, np.float32), atol=tol)
+
+
+def test_ssd_scan_grads_match_ref():
+    """The scan's custom VJP differentiates the reference: gradients of
+    a loss over y and the final state match the reference's own."""
+    b, l, h, p, g, n, chunk = 1, 64, 2, 8, 1, 8, 32
+    ks = jax.random.split(KEY, 6)
+    x = jax.random.normal(ks[0], (b, l, h, p)) * 0.5
+    dt = jax.nn.softplus(jax.random.normal(ks[1], (b, l, h))) * 0.2
+    A = -jnp.exp(jax.random.normal(ks[2], (h,)) * 0.3)
+    B = jax.random.normal(ks[3], (b, l, g, n)) * 0.3
+    C = jax.random.normal(ks[4], (b, l, g, n)) * 0.3
+    D = jnp.ones((h,))
+    wy = jax.random.normal(ks[5], (b, l, h, p))
+
+    def loss(fn):
+        def f(*args):
+            y, st_final = fn(*args)
+            return jnp.sum(y * wy) + jnp.sum(st_final)
+        return f
+
+    args = (x, dt, A, B, C, D)
+    got = jax.grad(loss(lambda *a: ssd_scan(*a, chunk=chunk,
+                                            interpret=True)),
+                   argnums=tuple(range(6)))(*args)
+    want = jax.grad(loss(lambda *a: ssd_ref(*a, chunk=chunk)),
+                    argnums=tuple(range(6)))(*args)
+    for gv, r in zip(got, want):
+        np.testing.assert_allclose(np.asarray(gv), np.asarray(r),
+                                   atol=1e-4, rtol=1e-4)
 
 
 # ---------------------------------------------------------------------------

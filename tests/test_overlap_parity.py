@@ -8,10 +8,14 @@ family — lm, ssm, moe, lenet — against the single-device full-batch
 gradient, with the same tiered tolerances as tests/test_sharded_step.py
 (which covers the legacy eager-gather body):
 
-* "none"    — fp32 reduction-ordering noise only. The floor is 2e-5,
-  not 1e-5: the partitioned path re-associates matmul reductions across
-  ranks (column-split contractions psum partial products), which the
-  mamba2 scan amplifies to ~1.2e-5 on this host.
+* "none"    — fp32 reduction-ordering noise only (floor 2e-5: the
+  partitioned path re-associates matmul reductions across ranks).
+  The gradient is read back as (p0 - p1) / lr, which resolves it only
+  to half an f32 ulp of the parameter over lr. At lr 1e-2 that was
+  2.4e-5 for mamba2's dt_bias (|p| up to 6.9) — above the floor, on
+  every mesh and on the legacy body alike, while the single-device
+  fp32 gradient agrees with float64 to 2e-6. So the arch cases take
+  one sgd step at lr 1, where the readback resolves 2.4e-7.
 * "int8"    — one shared-scale int8 ulp of the per-shard grad maxima.
 * "int8_ef" — same bound step-1; the residual buffer must engage.
 
@@ -59,7 +63,7 @@ from repro.train import (init_sharded_train_state, make_sharded_train_step,
 
 cfg = reduced(get_config(ARCH), **RED)
 cfg = dataclasses.replace(cfg, dtype="float32", param_dtype="float32")
-LR, B, S = 1e-2, 8, 32
+LR, B, S = 1.0, 8, 32
 batch = make_batch_for(cfg, B, S, step=0)
 
 ref_params = MD.init_model(jax.random.PRNGKey(0), cfg)

@@ -138,7 +138,6 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import json
 import jax, jax.numpy as jnp, numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 from repro.dist.compression import compressed_psum_mean_ef
 from repro.launch.mesh import make_mesh
@@ -159,8 +158,8 @@ def run(xs):
             m, err = compressed_psum_mean_ef(xs[t], "data", err)
             applied = applied + m
         return applied                 # replicated (post-psum)
-    return shard_map(body, mesh=mesh, in_specs=P(None, "data"),
-                     out_specs=P(), check_rep=False)(xs)
+    return jax.shard_map(body, mesh=mesh, in_specs=P(None, "data"),
+                         out_specs=P(), check_vma=False)(xs)
 
 applied = np.asarray(run(xs.reshape(T, 4 * N)))
 true = np.asarray(xs.mean(axis=1).sum(axis=0))

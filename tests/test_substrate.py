@@ -131,11 +131,10 @@ def test_compress_tree_modes_and_ef_plumbing():
 def test_compressed_psum_matches_mean():
     """shard_map int8 all-reduce-mean == plain mean on a 1-device mesh."""
     from jax.sharding import Mesh, PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
     mesh = jax.make_mesh((1,), ("data",))
     x = jnp.asarray(np.random.default_rng(0).normal(size=(8,)))
-    f = shard_map(lambda v: compressed_psum_mean(v, "data"), mesh=mesh,
-                  in_specs=P(), out_specs=P())
+    f = jax.shard_map(lambda v: compressed_psum_mean(v, "data"), mesh=mesh,
+                      in_specs=P(), out_specs=P())
     np.testing.assert_allclose(np.asarray(f(x)), np.asarray(x), atol=2e-2)
 
 

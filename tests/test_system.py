@@ -117,13 +117,14 @@ import jax, json
 import jax.numpy as jnp
 from repro.configs import get_config, reduced, TrainConfig, get_shape
 from repro.configs.base import ShapeConfig
+from repro.launch.mesh import make_mesh
 from repro.launch.specs import input_specs
 from repro.perf.roofline import roofline_from_compiled
-mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
 cfg = reduced(get_config("qwen2.5-3b"), d_model=64, vocab=512)
 shape = ShapeConfig("tiny_train", 64, 8, "train")
 prog = input_specs(cfg, shape, mesh, TrainConfig(remat_policy="none"))
-with mesh:
+with jax.set_mesh(mesh):
     lowered = jax.jit(prog.fn, in_shardings=prog.in_shardings,
                       donate_argnums=prog.donate_argnums).lower(*prog.args)
     compiled = lowered.compile()

@@ -1,0 +1,119 @@
+"""The main path's Pallas kernels compile for a TPU v5e at real widths.
+
+Interpret mode (tests/test_kernels.py) checks numerics but not what the
+TPU compiler accepts: block shapes that break the (8, 128) tiling, 1-D
+vectors whose Mosaic layout disagrees with XLA's, scalars stored to
+VMEM. Here each kernel is lowered and compiled for one chip of a
+described (not attached) v5e:2x2, so a layout the compiler refuses
+fails a test instead of a chip run. Nothing executes.
+
+The topology is described inside a fixture, never at import: only one
+process may load the TPU compiler library at a time, and a worker that
+cannot load it skips this file's tests instead of failing collection.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels.flash_attention import flash_attention
+from repro.kernels.quantize import (dequantize_int8_pallas,
+                                    quantize_int8_pallas)
+from repro.kernels.ssd_scan import ssd_scan
+from repro.models.attention import AttnSpec
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    # a compile for a described chip is written to the persistent cache
+    # but can never be read back without one: keep the cache out of it
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        jax.config.update("jax_enable_compilation_cache", was)
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+
+
+def _compiled_text(fn, *shapes):
+    return jax.jit(fn).lower(*shapes).compile().as_text()
+
+
+def _spec(sharding, shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+# (q heads, kv heads, head dim): qwen2.5-3b and smollm-360m
+ATTN_WIDTHS = {"qwen2.5-3b": (16, 2, 128), "smollm-360m": (15, 5, 64)}
+SEQ = 2048
+KV_BLOCK = 1024        # ModelConfig.attn_block, what the model passes
+
+
+@pytest.mark.parametrize("arch", sorted(ATTN_WIDTHS))
+def test_flash_prefill_compiles(one_chip, arch):
+    hq, hkv, hd = ATTN_WIDTHS[arch]
+    spec = AttnSpec(causal=True)
+    shapes = [_spec(one_chip, (1, SEQ, hq, hd), jnp.bfloat16),
+              _spec(one_chip, (1, SEQ, hkv, hd), jnp.bfloat16),
+              _spec(one_chip, (1, SEQ, hkv, hd), jnp.bfloat16),
+              _spec(one_chip, (SEQ,), jnp.int32),
+              _spec(one_chip, (SEQ,), jnp.int32)]
+    txt = _compiled_text(
+        lambda q, k, v, qp, kp: flash_attention(q, k, v, qp, kp, spec,
+                                                block_kv=KV_BLOCK), *shapes)
+    assert "tpu_custom_call" in txt
+
+
+@pytest.mark.parametrize("arch", sorted(ATTN_WIDTHS))
+def test_flash_decode_compiles(one_chip, arch):
+    hq, hkv, hd = ATTN_WIDTHS[arch]
+    batch, cap = 8, SEQ
+    spec = AttnSpec(causal=True)
+    shapes = [_spec(one_chip, (batch, 1, hq, hd), jnp.bfloat16),
+              _spec(one_chip, (batch, cap, hkv, hd), jnp.bfloat16),
+              _spec(one_chip, (batch, cap, hkv, hd), jnp.bfloat16),
+              _spec(one_chip, (1,), jnp.int32),
+              _spec(one_chip, (cap,), jnp.int32)]
+    txt = _compiled_text(
+        lambda q, k, v, qp, kp: flash_attention(q, k, v, qp, kp, spec,
+                                                block_kv=KV_BLOCK), *shapes)
+    assert "tpu_custom_call" in txt
+
+
+def test_ssd_scan_compiles_mamba2_widths(one_chip):
+    # mamba2-370m: 32 heads of 64, state 128, one B/C group, chunk 256
+    b, l, h, p, g, n = 1, SEQ, 32, 64, 1, 128
+    shapes = [_spec(one_chip, (b, l, h, p), jnp.bfloat16),
+              _spec(one_chip, (b, l, h), jnp.float32),
+              _spec(one_chip, (h,), jnp.float32),
+              _spec(one_chip, (b, l, g, n), jnp.bfloat16),
+              _spec(one_chip, (b, l, g, n), jnp.bfloat16),
+              _spec(one_chip, (h,), jnp.float32)]
+    txt = _compiled_text(lambda *a: ssd_scan(*a, chunk=256), *shapes)
+    assert "tpu_custom_call" in txt
+
+
+LEAF = (4096, 1024)    # one gradient leaf on the int8 wire
+
+
+def test_quantize_int8_compiles(one_chip):
+    txt = _compiled_text(quantize_int8_pallas,
+                         _spec(one_chip, LEAF, jnp.float32))
+    assert "tpu_custom_call" in txt
+
+
+def test_dequantize_int8_compiles(one_chip):
+    txt = _compiled_text(dequantize_int8_pallas,
+                         _spec(one_chip, LEAF, jnp.int8),
+                         _spec(one_chip, (), jnp.float32))
+    assert "tpu_custom_call" in txt

@@ -96,7 +96,7 @@ def main():
         sh)
     step = jax.jit(make_sharded_train_step(cfg, tcfg, mesh, STRATEGY),
                    in_shardings=(sh, None), out_shardings=(sh, None))
-    with mesh:
+    with jax.set_mesh(mesh):
         state, m = step(state, batch)          # compile
     jax.block_until_ready(m["loss"])
 
@@ -106,7 +106,7 @@ def main():
         with rec.span("step", category="train", step_num=i,
                       phase="steady"):
             with rec.span("dispatch", category="train"):
-                with mesh:
+                with jax.set_mesh(mesh):
                     state, m = step(state, batch)
             with rec.span("wait", category="train"):
                 jax.block_until_ready(m["loss"])

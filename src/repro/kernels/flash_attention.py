@@ -152,6 +152,7 @@ def _flash_forward(q, k, v, q_pos, kv_pos, spec: AttnSpec, block_q: int,
             pltpu.VMEM((block_q, hd), jnp.float32),     # acc
         ],
         interpret=interpret,
+        name="flash_fwd",
     )(qf, kf, vf, q_pos.astype(jnp.int32).reshape(Sq_p, 1),
       kv_pos.astype(jnp.int32).reshape(1, Skv_p))
 

@@ -96,6 +96,7 @@ def quantize_int8_pallas(x: jax.Array, *, block_rows: int = 64,
         out_shape=jax.ShapeDtypeStruct((n_blocks * SUBLANES, LANES),
                                        jnp.float32),
         interpret=interpret,
+        name="int8_absmax",
     )(tiles)
     scale = jnp.max(partial_max).reshape(1, 1) / 127.0
     q = pl.pallas_call(
@@ -105,6 +106,7 @@ def quantize_int8_pallas(x: jax.Array, *, block_rows: int = 64,
         out_specs=pl.BlockSpec(block, lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct(tiles.shape, jnp.int8),
         interpret=interpret,
+        name="int8_quantize",
     )(tiles, scale)
     return q.reshape(-1)[:x.size].reshape(x.shape), scale.reshape(())
 
@@ -124,5 +126,6 @@ def dequantize_int8_pallas(q: jax.Array, scale: jax.Array, *,
         out_specs=pl.BlockSpec(block, lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct(tiles.shape, jnp.float32),
         interpret=interpret,
+        name="int8_dequantize",
     )(tiles, jnp.asarray(scale, jnp.float32).reshape(1, 1))
     return out.reshape(-1)[:q.size].reshape(q.shape)

@@ -138,6 +138,7 @@ def _ssd_forward(x, dt, A, B, C, D, chunk, interpret):
         ],
         scratch_shapes=[pltpu.VMEM((p, n), jnp.float32)],
         interpret=interpret,
+        name="ssd_scan_fwd",
     )(x.transpose(0, 2, 1, 3), dt[..., None], dt[:, :, None, :],
       A.astype(jnp.float32), B.transpose(0, 2, 1, 3),
       C.transpose(0, 2, 1, 3), D.astype(jnp.float32))

@@ -20,6 +20,7 @@ collectives. Requesting a strategy that cannot actually shard (a
 from __future__ import annotations
 
 import argparse
+import contextlib
 import json
 import os
 import sys
@@ -44,7 +45,8 @@ def build_parser() -> argparse.ArgumentParser:
                          "when --strategy is set, else no pool)")
     ap.add_argument("--trace-dir", default="",
                     help="record prefill/decode spans and write "
-                         "trace.jsonl + trace_chrome.json here; empty "
+                         "trace.jsonl here, with a jax.profiler trace of "
+                         "the run (the spans on its host plane); empty "
                          "(default) keeps the zero-overhead disabled "
                          "recorder")
     ap.add_argument("--dry-run", action="store_true",
@@ -70,7 +72,7 @@ def main(argv=None):
     from repro.launch.mesh import make_mesh
     from repro.launch.specs import cache_specs, params_only_shardings
     from repro.models import model as MD
-    from repro.obs import Metrics, Recorder, write_chrome_trace, write_jsonl
+    from repro.obs import Metrics, Recorder, use_recorder, write_jsonl
     from repro.train.ft import plan_remesh
 
     cfg = get_config(args.arch)
@@ -143,7 +145,9 @@ def main(argv=None):
 
     t0 = time.time()
     logits = None
-    with jax.set_mesh(mesh):
+    profile = (jax.profiler.trace(args.trace_dir) if rec.enabled
+               else contextlib.nullcontext())
+    with jax.set_mesh(mesh), use_recorder(rec), profile:
         with rec.span("prefill", category="serve", batch=B, tokens=S):
             for pos in range(S):               # batched prefill-by-decode
                 logits, caches = decode(params, caches,
@@ -189,8 +193,6 @@ def main(argv=None):
                     meta={"arch": cfg.name, "mode": "serve",
                           "strategy": args.strategy or None,
                           "devices": n_dev})
-        write_chrome_trace(
-            os.path.join(args.trace_dir, "trace_chrome.json"), rec)
         report["trace"] = {"dir": args.trace_dir,
                            "spans": len(rec.spans)}
     print(json.dumps(report))

@@ -33,6 +33,7 @@ import os
 import time
 
 DEFAULT_POOL = 8      # placeholder pool forced on single-CPU hosts
+PROFILE_STEPS = 3     # steady steps in the --trace-dir profiler trace
 
 
 def _force_host_pool(n: int) -> None:
@@ -136,19 +137,17 @@ def build_parser() -> argparse.ArgumentParser:
                          "calibrated cost model (repro.perf.costmodel) "
                          "and include it in the plan output")
     ap.add_argument("--trace-dir", default="",
-                    help="record spans/metrics and write trace.jsonl + "
-                         "trace_chrome.json here; empty (default) keeps "
-                         "the zero-overhead disabled recorder")
+                    help="record spans/metrics and write trace.jsonl "
+                         "here, with a jax.profiler trace of the first "
+                         f"{PROFILE_STEPS} steady steps (the spans on its "
+                         "host plane); empty (default) keeps the "
+                         "zero-overhead disabled recorder")
     ap.add_argument("--trace-sync", default="none",
                     choices=["none", "boundary"],
                     help="device-sync policy at span boundaries: 'none' "
                          "never adds a sync the untraced path lacks "
                          "(preserves comm/compute overlap); 'boundary' "
                          "blocks for precise span durations")
-    ap.add_argument("--trace-annotate", action="store_true",
-                    help="pass step spans through "
-                         "jax.profiler.StepTraceAnnotation (groups device "
-                         "activity by step in a jax.profiler trace)")
     ap.add_argument("--dry-run", action="store_true",
                     help="print the execution plan as JSON and exit")
     return ap
@@ -195,10 +194,50 @@ def _pick_mode(args, tcfg, mesh, n_dev: int):
     return "sharded", "auto"
 
 
+class _StepProfile:
+    """A ``jax.profiler`` trace of the first ``steps`` steady steps, under
+    ``trace_dir`` (no trace when it is empty)."""
+
+    def __init__(self, trace_dir: str, steps: int):
+        self.trace_dir, self.left, self.on = trace_dir, steps, False
+
+    def before_step(self, phase: str) -> None:
+        if self.trace_dir and self.left and not self.on and \
+                phase == "steady":
+            import jax
+            jax.profiler.start_trace(self.trace_dir)
+            self.on = True
+
+    def after_step(self) -> None:
+        if self.on:
+            self.left -= 1
+            if not self.left:
+                self.close()
+
+    def close(self) -> None:
+        if self.on:
+            import jax
+            jax.profiler.stop_trace()
+            self.on = False
+
+
 def main(argv=None):
     args = build_parser().parse_args(argv)
     _force_host_pool(args.devices or DEFAULT_POOL)
+    from repro.obs import Recorder, use_recorder
 
+    # the current recorder: a compile lands as an event in its open span
+    rec = Recorder(enabled=bool(args.trace_dir),
+                   sync_policy=args.trace_sync)
+    profile = _StepProfile(args.trace_dir, PROFILE_STEPS)
+    try:
+        with use_recorder(rec):
+            return _train(args, rec, profile)
+    finally:
+        profile.close()
+
+
+def _train(args, rec, profile: _StepProfile):
     import jax
     import numpy as np
 
@@ -218,14 +257,12 @@ def main(argv=None):
     from repro.train.ft import StragglerDetector, plan_recovery, plan_remesh
     from repro.train.supervisor import (RetryPolicy, Supervisor,
                                         SurvivorPrecompiler, pow2_floor)
-    from repro.obs import (Metrics, Recorder, StragglerMonitor,
-                           collective_bytes, observe_step,
+    from repro.obs import (CompileCounts, Metrics, StragglerMonitor,
+                           collective_bytes, compile_counts, observe_step,
                            record_memory_watermarks, record_recovery,
-                           write_chrome_trace, write_jsonl)
+                           write_jsonl)
 
-    rec = Recorder(enabled=bool(args.trace_dir),
-                   sync_policy=args.trace_sync,
-                   annotate=args.trace_annotate)
+    run_compiles = compile_counts()
     obs_metrics = Metrics()
     sup = Supervisor(policy=RetryPolicy(max_attempts=max(args.max_retries,
                                                          1)),
@@ -452,6 +489,7 @@ def main(argv=None):
     monitor = StragglerMonitor(detector, metrics=obs_metrics, recorder=rec)
     comm_terms = _comm_byte_terms()
     phase = "warmup"             # the first step pays the jit compile
+    steady_compiles = CompileCounts()
     precomp_submitted = False
     loss_by_step = {}
     step_times = []
@@ -561,6 +599,8 @@ def main(argv=None):
             step_times = []
             step = ckpt_step
             continue
+        profile.before_step(phase)
+        compiled_before = compile_counts()
         with rec.span("step", category="train", step_num=step,
                       phase=phase) as sp:
             with rec.span("data", category="train"):
@@ -576,6 +616,9 @@ def main(argv=None):
                 jax.block_until_ready(metrics["loss"])
             dt = time.perf_counter() - t0
             sp.set(ms=dt * 1e3)
+        profile.after_step()
+        if phase == "steady":
+            steady_compiles += compile_counts() - compiled_before
         if recovery is not None and "first_step_s" not in recovery:
             # first post-recovery step: on the re-jit path it includes
             # the compile (the largest share of measured recovery
@@ -639,7 +682,9 @@ def main(argv=None):
                "largest_param": list(big.shape),
                "shards": [[s.device.id, list(s.data.shape)]
                           for s in big.addressable_shards]},
-           "straggler_flags": detector.flags}
+           "straggler_flags": detector.flags,
+           "compiles": {"steady_steps": steady_compiles.to_dict(),
+                        "run": (compile_counts() - run_compiles).to_dict()}}
     out["supervisor"] = {"retries": sup.retries,
                          "proactive_checkpoints": sup.proactive_checkpoints}
     if precomp is not None:
@@ -654,13 +699,27 @@ def main(argv=None):
                 "sync_policy": args.trace_sync}
         write_jsonl(os.path.join(args.trace_dir, "trace.jsonl"), rec,
                     metrics=obs_metrics.to_dict(), meta=meta)
-        write_chrome_trace(
-            os.path.join(args.trace_dir, "trace_chrome.json"), rec)
+        profile.close()
+        if hasattr(step_fn, "lower"):     # not a precompiled survivor
+            # the profile's device events name the instructions of this
+            # text, which carry the op_name (layer scope) of their code
+            with jax.set_mesh(mesh):
+                text = step_fn.lower(state, example_batch).compile()
+            with open(os.path.join(args.trace_dir, "step.hlo.txt"),
+                      "w") as f:
+                f.write(text.as_text())
         out["trace"] = {"dir": args.trace_dir, "spans": len(rec.spans),
-                        "events": len(rec.events)}
+                        "events": len(rec.events),
+                        "xplane": _xplanes(args.trace_dir)}
         out["metrics"] = obs_metrics.to_dict()
     print(json.dumps(out))
     return out
+
+
+def _xplanes(trace_dir: str):
+    """The profiler traces written under ``trace_dir``."""
+    return sorted(os.path.join(d, f) for d, _, fs in os.walk(trace_dir)
+                  for f in fs if f.endswith(".xplane.pb"))
 
 
 if __name__ == "__main__":

@@ -254,57 +254,74 @@ def apply_block(params: Params, x, cfg: ModelConfig, kind: str, *,
                                 cache_pos=cache_pos, window=0)
         return x, (c0, c1), a0 + a1
 
+    # each sublayer (pre-norm, body, residual add) under its layer scope
     if kind in ("attn_mlp", "enc_attn"):
         spec = _attn_spec(cfg, causal=causal, window=window)
-        h, new_cache = A.gqa_forward(params["attn"],
-                                     rmsnorm(params["ln1"], x, eps), cfg,
-                                     spec, positions, cache, cache_pos)
-        x = x + h
-        x = x + mlp(params["mlp"], rmsnorm(params["ln2"], x, eps),
-                    cfg.mlp_activation)
+        with jax.named_scope("attention"):
+            h, new_cache = A.gqa_forward(params["attn"],
+                                         rmsnorm(params["ln1"], x, eps), cfg,
+                                         spec, positions, cache, cache_pos)
+            x = x + h
+        with jax.named_scope("mlp"):
+            x = x + mlp(params["mlp"], rmsnorm(params["ln2"], x, eps),
+                        cfg.mlp_activation)
         return x, new_cache, aux
 
     if kind in ("mla_mlp", "mla_moe"):
         spec = _attn_spec(cfg, causal=causal, window=window)
-        h, new_cache = A.mla_forward(params["attn"],
-                                     rmsnorm(params["ln1"], x, eps), cfg,
-                                     spec, positions, cache, cache_pos)
-        x = x + h
-        inner = rmsnorm(params["ln2"], x, eps)
+        with jax.named_scope("attention"):
+            h, new_cache = A.mla_forward(params["attn"],
+                                         rmsnorm(params["ln1"], x, eps), cfg,
+                                         spec, positions, cache, cache_pos)
+            x = x + h
         if kind == "mla_mlp":
-            x = x + mlp(params["mlp"], inner, cfg.mlp_activation)
+            with jax.named_scope("mlp"):
+                x = x + mlp(params["mlp"], rmsnorm(params["ln2"], x, eps),
+                            cfg.mlp_activation)
         else:
-            out = M.moe_forward(params["moe"], inner, cfg)
-            x, aux = x + out.y, out.aux_loss
+            with jax.named_scope("moe"):
+                out = M.moe_forward(params["moe"],
+                                    rmsnorm(params["ln2"], x, eps), cfg)
+                x, aux = x + out.y, out.aux_loss
         return x, new_cache, aux
 
     if kind == "attn_moe":
         spec = _attn_spec(cfg, causal=causal, window=window)
-        h, new_cache = A.gqa_forward(params["attn"],
-                                     rmsnorm(params["ln1"], x, eps), cfg,
-                                     spec, positions, cache, cache_pos)
-        x = x + h
-        out = M.moe_forward(params["moe"], rmsnorm(params["ln2"], x, eps), cfg)
-        return x + out.y, new_cache, out.aux_loss
+        with jax.named_scope("attention"):
+            h, new_cache = A.gqa_forward(params["attn"],
+                                         rmsnorm(params["ln1"], x, eps), cfg,
+                                         spec, positions, cache, cache_pos)
+            x = x + h
+        with jax.named_scope("moe"):
+            out = M.moe_forward(params["moe"], rmsnorm(params["ln2"], x, eps),
+                                cfg)
+            x = x + out.y
+        return x, new_cache, out.aux_loss
 
     if kind == "ssm":
-        h, new_cache = S.mamba2_forward(params["mamba"],
-                                        rmsnorm(params["ln"], x, eps), cfg,
-                                        cache)
-        return x + h, new_cache, aux
+        with jax.named_scope("ssd"):
+            h, new_cache = S.mamba2_forward(params["mamba"],
+                                            rmsnorm(params["ln"], x, eps),
+                                            cfg, cache)
+            x = x + h
+        return x, new_cache, aux
 
     if kind == "dec_attn":
         spec = _attn_spec(cfg, causal=True)
-        h, self_cache = A.gqa_forward(params["attn"],
-                                      rmsnorm(params["ln1"], x, eps), cfg,
-                                      spec, positions, cache, cache_pos)
-        x = x + h
-        h, _ = A.gqa_forward(params["xattn"], rmsnorm(params["ln2"], x, eps),
-                             cfg, AttnSpec(causal=False), positions,
-                             kv_override=enc_kv)
-        x = x + h
-        x = x + mlp(params["mlp"], rmsnorm(params["ln3"], x, eps),
-                    cfg.mlp_activation)
+        with jax.named_scope("attention"):
+            h, self_cache = A.gqa_forward(params["attn"],
+                                          rmsnorm(params["ln1"], x, eps),
+                                          cfg, spec, positions, cache,
+                                          cache_pos)
+            x = x + h
+            h, _ = A.gqa_forward(params["xattn"],
+                                 rmsnorm(params["ln2"], x, eps), cfg,
+                                 AttnSpec(causal=False), positions,
+                                 kv_override=enc_kv)
+            x = x + h
+        with jax.named_scope("mlp"):
+            x = x + mlp(params["mlp"], rmsnorm(params["ln3"], x, eps),
+                        cfg.mlp_activation)
         return x, self_cache, aux
 
     raise ValueError(kind)
@@ -438,10 +455,11 @@ def apply_segment(params: Params, x, cfg: ModelConfig, seg: SegmentSpec, *,
 # ---------------------------------------------------------------------------
 
 def embed_tokens(params, cfg: ModelConfig, tokens):
-    h = params["embed"]["table"].value[tokens]
-    if cfg.scale_embeddings:
-        h = h * jnp.asarray(math.sqrt(cfg.d_model), h.dtype)
-    return h
+    with jax.named_scope("embed"):
+        h = params["embed"]["table"].value[tokens]
+        if cfg.scale_embeddings:
+            h = h * jnp.asarray(math.sqrt(cfg.d_model), h.dtype)
+        return h
 
 
 def encoder_forward(params, cfg: ModelConfig, frames, remat="none"):
@@ -469,21 +487,24 @@ def hidden_forward(params, cfg: ModelConfig, h, *, positions, caches=None,
                                  keep_cache=keep_cache, remat=remat)
         new_caches.append(nc)
         aux = aux + a
-    h = rmsnorm(params["final_norm"], h, cfg.norm_eps)
+    with jax.named_scope("head"):
+        h = rmsnorm(params["final_norm"], h, cfg.norm_eps)
     return h, new_caches, aux
 
 
 def logits_fn(params, cfg: ModelConfig, h):
-    if cfg.tie_embeddings or "lm_head" not in params:
-        logits = unembed(params["embed"], h)
-    else:
-        logits = jnp.einsum("...d,dv->...v", h,
-                            params["lm_head"]["kernel"].value,
-                            preferred_element_type=jnp.float32)
-    if cfg.final_logit_softcap:
-        logits = softcap(logits, cfg.final_logit_softcap)
-    logits = maybe_constrain(logits, *([None] * (logits.ndim - 1)), "model")
-    return logits.astype(jnp.bfloat16)   # sharded [.., vocab]; CE in fp32
+    with jax.named_scope("head"):
+        if cfg.tie_embeddings or "lm_head" not in params:
+            logits = unembed(params["embed"], h)
+        else:
+            logits = jnp.einsum("...d,dv->...v", h,
+                                params["lm_head"]["kernel"].value,
+                                preferred_element_type=jnp.float32)
+        if cfg.final_logit_softcap:
+            logits = softcap(logits, cfg.final_logit_softcap)
+        logits = maybe_constrain(logits, *([None] * (logits.ndim - 1)),
+                                 "model")
+        return logits.astype(jnp.bfloat16)   # sharded [.., vocab]; CE fp32
 
 
 # ---------------------------------------------------------------------------
@@ -501,18 +522,18 @@ def cross_entropy(logits, labels, impl: str = "gather"):
       reduction over the (sharded) vocab axis — lowers to an elementwise
       select + per-shard reduce + tiny psum; no logits all-gather.
     """
-    lf = logits.astype(jnp.float32)
-    lse = jax.nn.logsumexp(lf, axis=-1)
-    lab = jnp.maximum(labels, 0)
-    if impl == "onehot":
-        V = lf.shape[-1]
-        iota = jax.lax.broadcasted_iota(jnp.int32, lf.shape, lf.ndim - 1)
-        ll = jnp.sum(jnp.where(iota == lab[..., None], lf, 0.0), axis=-1)
-    else:
-        ll = jnp.take_along_axis(lf, lab[..., None], axis=-1)[..., 0]
-    mask = (labels != MASK_ID)
-    ce = (lse - ll) * mask
-    return ce.sum(), mask.sum()
+    with jax.named_scope("head"):
+        lf = logits.astype(jnp.float32)
+        lse = jax.nn.logsumexp(lf, axis=-1)
+        lab = jnp.maximum(labels, 0)
+        if impl == "onehot":
+            iota = jax.lax.broadcasted_iota(jnp.int32, lf.shape, lf.ndim - 1)
+            ll = jnp.sum(jnp.where(iota == lab[..., None], lf, 0.0), axis=-1)
+        else:
+            ll = jnp.take_along_axis(lf, lab[..., None], axis=-1)[..., 0]
+        mask = (labels != MASK_ID)
+        ce = (lse - ll) * mask
+        return ce.sum(), mask.sum()
 
 
 def loss_fn(params, cfg: ModelConfig, batch: Dict[str, jax.Array], *,
@@ -537,19 +558,21 @@ def loss_fn(params, cfg: ModelConfig, batch: Dict[str, jax.Array], *,
     h, _, aux = hidden_forward(params, cfg, h, positions=positions,
                                remat=remat)
 
-    if "labels" in batch:
-        labels = batch["labels"]
-    else:
-        labels = jnp.concatenate(
-            [tokens[:, 1:], jnp.full((B, 1), MASK_ID, tokens.dtype)], axis=1)
-    if cfg.frontend == "vision_patch_stub":
-        n_f = batch["patches"].shape[1]
-        labels = jnp.concatenate(
-            [jnp.full((B, n_f), MASK_ID, labels.dtype), labels], axis=1)
+    with jax.named_scope("head"):
+        if "labels" in batch:
+            labels = batch["labels"]
+        else:
+            labels = jnp.concatenate(
+                [tokens[:, 1:], jnp.full((B, 1), MASK_ID, tokens.dtype)],
+                axis=1)
+        if cfg.frontend == "vision_patch_stub":
+            n_f = batch["patches"].shape[1]
+            labels = jnp.concatenate(
+                [jnp.full((B, n_f), MASK_ID, labels.dtype), labels], axis=1)
 
-    logits = logits_fn(params, cfg, h)
-    ce_sum, n_tok = cross_entropy(logits, labels, impl=ce_impl)
-    loss = ce_sum / jnp.maximum(n_tok, 1)
+        logits = logits_fn(params, cfg, h)
+        ce_sum, n_tok = cross_entropy(logits, labels, impl=ce_impl)
+        loss = ce_sum / jnp.maximum(n_tok, 1)
     metrics = {"ce": loss, "aux": aux, "tokens": n_tok}
 
     if cfg.mtp_depth and not cfg.is_encoder_decoder:

@@ -1,17 +1,11 @@
-"""Serialization of recorded traces: JSONL event log + Chrome trace.
+"""Serialization of recorded traces as a JSONL event log.
 
-Two formats, both derived from the same ``Recorder`` contents:
-
-* **JSONL** — one JSON object per line; spans (``type: "span"``), events
-  (``type: "event"``), and an optional trailing metrics snapshot
-  (``type: "metrics"``). Round-trips losslessly through
-  ``read_jsonl`` → ``Recorder``-shaped ``TraceData``.
-
-* **Chrome trace / Perfetto** — the ``traceEvents`` JSON array format
-  (``ph: "X"`` complete events with microsecond ``ts``/``dur``,
-  ``ph: "i"`` instants for recorder events), loadable in
-  ``chrome://tracing`` or https://ui.perfetto.dev. Span categories map
-  to ``cat``; attrs map to ``args``.
+One JSON object per line; spans (``type: "span"``), events (``type:
+"event"``), and an optional trailing metrics snapshot (``type:
+"metrics"``). Round-trips losslessly through ``read_jsonl`` →
+``Recorder``-shaped ``TraceData``. The times are the recorder's clock;
+the same spans sit on the device's clock in a ``jax.profiler`` trace
+(``repro.obs.trace``), which is where they line up with device work.
 """
 from __future__ import annotations
 
@@ -80,41 +74,3 @@ def read_jsonl(path) -> TraceData:
             elif kind == "meta":
                 data.meta = {k: v for k, v in rec.items() if k != "type"}
     return data
-
-
-def chrome_trace(rec: Recorder, *, pid: int = 1, tid: int = 1,
-                 process_name: str = "repro") -> Dict[str, Any]:
-    """The recorder's contents as a Chrome-trace ``traceEvents`` dict.
-
-    All spans ran on one host thread (the recorder is a single nested
-    stack), so one pid/tid lane reproduces the nesting visually; the
-    viewer stacks overlapping ``ph:"X"`` events by start time."""
-    t0 = min([s.t_start for s in rec.spans]
-             + [e["t"] for e in rec.events], default=0.0)
-
-    def us(t: float) -> float:
-        return (t - t0) * 1e6
-
-    events: List[Dict[str, Any]] = [{
-        "name": "process_name", "ph": "M", "pid": pid, "tid": tid,
-        "args": {"name": process_name}}]
-    for sp in rec.spans:
-        if sp.t_end is None:
-            continue
-        events.append({
-            "name": sp.name, "cat": sp.category or "span", "ph": "X",
-            "pid": pid, "tid": tid, "ts": us(sp.t_start),
-            "dur": us(sp.t_end) - us(sp.t_start),
-            "args": {**sp.attrs, "span_id": sp.span_id,
-                     "depth": sp.depth}})
-    for ev in rec.events:
-        events.append({
-            "name": ev["name"], "cat": "event", "ph": "i", "s": "t",
-            "pid": pid, "tid": tid, "ts": us(ev["t"]),
-            "args": dict(ev.get("attrs", {}))})
-    return {"traceEvents": events, "displayTimeUnit": "ms"}
-
-
-def write_chrome_trace(path, rec: Recorder, **kw) -> None:
-    with open(path, "w") as fh:
-        json.dump(chrome_trace(rec, **kw), fh, indent=1)

@@ -7,6 +7,10 @@ per-collective bytes from the calibrated schedules, device memory
 watermarks via ``Device.memory_stats()``, throughput in the sweep's own
 normalization units (samples/sec, tokens/sec), and straggler skew.
 
+``compile_counts`` snapshots a process-wide counter of backend compiles
+and persistent-cache loads, fed by one ``jax.monitoring`` listener; the
+difference of two snapshots counts what was compiled between them.
+
 ``StragglerMonitor`` is the live wiring of ``repro.train.ft.
 StragglerDetector``: it feeds the detector every measured step time,
 keeps the straggler-skew gauge current, and emits a
@@ -16,6 +20,8 @@ driver's former bare log line.
 """
 from __future__ import annotations
 
+import dataclasses
+import threading
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Sequence
 
@@ -118,6 +124,98 @@ class Metrics:
     def to_dict(self) -> Dict[str, Dict[str, Any]]:
         return {name: m.to_dict() for name, m in
                 sorted(self._by_name.items())}
+
+
+# ---------------------------------------------------------------------------
+# Compile counter
+# ---------------------------------------------------------------------------
+
+# JAX times every backend compile request with this event, a persistent-
+# cache hit included; a hit records CACHE_HIT first, in the same thread.
+BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
+CACHE_HIT = "/jax/compilation_cache/cache_hits"
+
+
+@dataclass(frozen=True)
+class CompileCounts:
+    """Backend compiles and persistent-cache loads, with their seconds;
+    ``later - earlier`` of two snapshots counts what happened between."""
+    compiles: int = 0
+    compile_s: float = 0.0
+    cache_loads: int = 0
+    cache_load_s: float = 0.0
+
+    def __sub__(self, other: "CompileCounts") -> "CompileCounts":
+        return CompileCounts(*(a - b for a, b in zip(
+            dataclasses.astuple(self), dataclasses.astuple(other))))
+
+    def __add__(self, other: "CompileCounts") -> "CompileCounts":
+        return CompileCounts(*(a + b for a, b in zip(
+            dataclasses.astuple(self), dataclasses.astuple(other))))
+
+    @property
+    def programs(self) -> int:
+        """Programs made ready to run: compiled or loaded."""
+        return self.compiles + self.cache_loads
+
+    @property
+    def seconds(self) -> float:
+        return self.compile_s + self.cache_load_s
+
+    def to_dict(self) -> Dict[str, float]:
+        return dataclasses.asdict(self)
+
+
+class _CompileListener:
+    """The ``jax.monitoring`` listener behind ``compile_counts``. It also
+    records a ``compile`` event in the open span of the current recorder
+    (a no-op while that recorder is disabled)."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._hit = threading.local()
+        self.counts = CompileCounts()
+
+    def on_event(self, event: str, **kw) -> None:
+        if event == CACHE_HIT:
+            self._hit.pending = True
+
+    def on_duration(self, event: str, seconds: float, **kw) -> None:
+        if event != BACKEND_COMPILE:
+            return
+        loaded = getattr(self._hit, "pending", False)
+        self._hit.pending = False
+        with self._lock:
+            c = self.counts
+            self.counts = (dataclasses.replace(
+                c, cache_loads=c.cache_loads + 1,
+                cache_load_s=c.cache_load_s + seconds) if loaded else
+                dataclasses.replace(c, compiles=c.compiles + 1,
+                                    compile_s=c.compile_s + seconds))
+        current_recorder().event(
+            "compile", kind="cache_load" if loaded else "backend",
+            seconds=float(seconds), fun=str(kw.get("fun_name", "")))
+
+
+_listener: Optional[_CompileListener] = None
+_listener_lock = threading.Lock()
+
+
+def compile_counts() -> CompileCounts:
+    """A snapshot of the process's compile counter.
+
+    The first call registers the listener (once per process: JAX's
+    listeners are process-wide), so what compiled before it is not
+    counted; a caller that wants set-up counted calls this first."""
+    global _listener
+    with _listener_lock:
+        if _listener is None:
+            from jax import monitoring
+            _listener = _CompileListener()
+            monitoring.register_event_listener(_listener.on_event)
+            monitoring.register_event_duration_secs_listener(
+                _listener.on_duration)
+        return _listener.counts
 
 
 # ---------------------------------------------------------------------------

@@ -3,9 +3,9 @@
 A ``Recorder`` collects *spans* (named, nested host-side intervals) and
 *events* (point-in-time structured records). Instrumented code paths —
 the train/serve drivers, the sweep drivers — open spans around their
-phases; ``repro.obs.export`` serializes the result as JSONL or a
-Chrome-trace/Perfetto file, and ``repro.obs.attribution`` aligns the
-spans against the cost model's own per-term predictions.
+phases; ``repro.obs.export`` serializes the result as JSONL, and
+``repro.obs.attribution`` aligns the spans against the cost model's own
+per-term predictions.
 
 Design constraints (docs/OBSERVABILITY.md):
 
@@ -27,10 +27,19 @@ Design constraints (docs/OBSERVABILITY.md):
   (The train driver already blocks on the loss every step; its "wait"
   child span times that pre-existing sync.)
 
-* **Profiler pass-through.** With ``annotate=True``, spans carrying a
-  ``step_num`` attribute additionally enter
-  ``jax.profiler.StepTraceAnnotation`` so a real ``jax.profiler`` trace
-  groups device activity by the same step boundaries the recorder saw.
+* **Profiler pass-through.** Every span of an enabled recorder also
+  enters ``jax.profiler.TraceAnnotation`` (``StepTraceAnnotation`` for
+  a span named ``step`` with a ``step_num``), so under a ``jax.profiler``
+  trace the spans sit on the host plane, on the device trace's clock,
+  and an idle gap on the device can be put down to the span that was
+  open. Outside a profiler trace an annotation records nothing.
+
+* **Layer scopes.** ``LAYER_SCOPES`` names the ``jax.named_scope`` the
+  model and the train step put around each layer (``repro.models.
+  model``, ``repro.train.step``). The scopes are trace-time metadata:
+  every HLO instruction of a layer carries the scope in its
+  ``op_name``, and a device trace is joined to the layers through the
+  compiled module's text (``bench/trace/scopes.py``).
 """
 from __future__ import annotations
 
@@ -41,6 +50,11 @@ from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional
 
 SYNC_POLICIES = ("none", "boundary")
+
+# The program's layer scopes (jax.named_scope names): a device op belongs
+# to the last of these in its op_name path.
+LAYER_SCOPES = ("embed", "attention", "mlp", "moe", "ssd", "head",
+                "optimizer")
 
 
 @dataclass
@@ -111,23 +125,22 @@ NULL_SPAN = _NullSpan()
 
 
 class _ActiveSpan:
-    """Context manager pairing one ``Span`` with its ``Recorder``."""
+    """Context manager pairing one ``Span`` with its ``Recorder`` and
+    the profiler annotation that puts it on the device trace's clock."""
     __slots__ = ("_rec", "span", "_annotation")
 
-    def __init__(self, rec: "Recorder", span: Span, annotation=None):
+    def __init__(self, rec: "Recorder", span: Span, annotation):
         self._rec = rec
         self.span = span
         self._annotation = annotation
 
     def __enter__(self) -> "_ActiveSpan":
         self._rec._push(self.span)
-        if self._annotation is not None:
-            self._annotation.__enter__()
+        self._annotation.__enter__()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
-        if self._annotation is not None:
-            self._annotation.__exit__(exc_type, exc, tb)
+        self._annotation.__exit__(exc_type, exc, tb)
         if exc_type is not None:
             self.span.attrs.setdefault("error", repr(exc))
         self._rec._pop(self.span)
@@ -154,20 +167,17 @@ class Recorder:
     """Span/event recorder with an on/off switch checked per call.
 
     ``clock`` is injectable for deterministic tests; ``sync_policy``
-    gates ``span.sync`` (see module docstring); ``annotate=True`` makes
-    spans with a ``step_num`` attribute pass through
-    ``jax.profiler.StepTraceAnnotation``."""
+    gates ``span.sync`` (see module docstring). An enabled recorder's
+    spans pass through the profiler's annotations."""
 
     def __init__(self, enabled: bool = True, *,
                  sync_policy: str = "none",
-                 annotate: bool = False,
                  clock: Callable[[], float] = time.perf_counter):
         if sync_policy not in SYNC_POLICIES:
             raise ValueError(f"sync_policy {sync_policy!r} not in "
                              f"{SYNC_POLICIES}")
         self.enabled = bool(enabled)
         self.sync_policy = sync_policy
-        self.annotate = bool(annotate)
         self.clock = clock
         self.spans: List[Span] = []
         self.events: List[Dict[str, Any]] = []
@@ -192,14 +202,12 @@ class Recorder:
                   parent_id=None if parent is None else parent.span_id,
                   t_start=self.clock(), category=category,
                   depth=len(self._stack), attrs=attrs)
-        annotation = None
-        if self.annotate and "step_num" in attrs:
-            try:
-                import jax.profiler
-                annotation = jax.profiler.StepTraceAnnotation(
-                    name, step_num=int(attrs["step_num"]))
-            except Exception:       # profiler unavailable: plain span
-                annotation = None
+        from jax import profiler
+        if name == "step" and "step_num" in attrs:
+            annotation = profiler.StepTraceAnnotation(
+                name, step_num=int(attrs["step_num"]))
+        else:
+            annotation = profiler.TraceAnnotation(name)
         return _ActiveSpan(self, sp, annotation)
 
     def event(self, name: str, **attrs) -> None:

@@ -109,18 +109,20 @@ def make_train_step(cfg: ModelConfig, tcfg: TrainConfig,
         loss, metrics, grads = _loss_and_grads(grad_fn, params, batch,
                                                microbatches)
 
-        # wire-format compression (numerics-exact w.r.t. a shared-scale
-        # compressed all-reduce; see dist/compression.py)
-        new_ef = state.ef
-        if tcfg.grad_compression != "none":
-            grads, new_ef = compress_tree(grads, tcfg.grad_compression,
-                                          state.ef)
+        with jax.named_scope("optimizer"):
+            # wire-format compression (numerics-exact w.r.t. a shared-scale
+            # compressed all-reduce; see dist/compression.py)
+            new_ef = state.ef
+            if tcfg.grad_compression != "none":
+                grads, new_ef = compress_tree(grads, tcfg.grad_compression,
+                                              state.ef)
 
-        grads, gnorm = clip_by_global_norm(grads, tcfg.grad_clip)
-        lr = warmup_cosine(state.opt.step, peak_lr=tcfg.learning_rate,
-                           warmup_steps=tcfg.warmup_steps,
-                           total_steps=tcfg.total_steps)
-        new_params, new_opt = opt_update(params, grads, state.opt, tcfg, lr)
+            grads, gnorm = clip_by_global_norm(grads, tcfg.grad_clip)
+            lr = warmup_cosine(state.opt.step, peak_lr=tcfg.learning_rate,
+                               warmup_steps=tcfg.warmup_steps,
+                               total_steps=tcfg.total_steps)
+            new_params, new_opt = opt_update(params, grads, state.opt, tcfg,
+                                             lr)
         metrics = dict(metrics)
         metrics.update(grad_norm=gnorm, lr=lr, loss=loss)
         return TrainState(new_params, new_opt, new_ef), metrics
@@ -471,7 +473,9 @@ def make_sharded_train_step(cfg: ModelConfig, tcfg: TrainConfig, mesh: Mesh,
                 metrics = jax.tree.map(
                     lambda m: jax.lax.pmean(m, batch_axes), metrics)
 
-            with jax.named_scope("obs:update"):
+            # the wire compression is the collective's (obs:grad_reduce)
+            with jax.named_scope("obs:update"), \
+                    jax.named_scope("optimizer"):
                 reduced, gnorm = clip_by_global_norm(reduced,
                                                      tcfg.grad_clip)
                 grads_shard = _zip_params(
@@ -551,7 +555,8 @@ def make_sharded_train_step(cfg: ModelConfig, tcfg: TrainConfig, mesh: Mesh,
             # the whole mesh makes the full-gradient norm — then the same
             # scale as clip_by_global_norm applies elementwise (scaling
             # commutes with the later slice).
-            with jax.named_scope("obs:update"):
+            with jax.named_scope("obs:update"), \
+                    jax.named_scope("optimizer"):
                 contribs = _zip_params(
                     lambda p, g, pl: jnp.sum(
                         jnp.square(g.astype(jnp.float32))) / pl.repl,

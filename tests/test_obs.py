@@ -11,13 +11,13 @@ import time
 
 import pytest
 
-from repro.obs import (Metrics, Recorder, StragglerMonitor, TermRow,
-                       attribution_table, chrome_trace, collective_bytes,
-                       current_recorder, detect_drift, observe_step,
-                       predicted_step_ms, predicted_terms, read_jsonl,
-                       render_markdown, set_recorder, span_coverage,
-                       straggler_skew, trace_lines, use_recorder,
-                       write_chrome_trace, write_jsonl)
+from repro.obs import (LAYER_SCOPES, CompileCounts, Metrics, Recorder,
+                       StragglerMonitor, TermRow, attribution_table,
+                       collective_bytes, compile_counts, current_recorder,
+                       detect_drift, observe_step, predicted_step_ms,
+                       predicted_terms, read_jsonl, render_markdown,
+                       set_recorder, span_coverage, straggler_skew,
+                       trace_lines, use_recorder, write_jsonl)
 from repro.perf.costmodel import Calibration, LinkParams, ScheduleInputs
 
 
@@ -244,21 +244,138 @@ def test_jsonl_round_trip(tmp_path):
         json.loads(line)
 
 
-def test_chrome_trace_format(tmp_path):
-    rec = _sample_recorder()
-    doc = chrome_trace(rec)
-    phases = {e["ph"] for e in doc["traceEvents"]}
-    assert {"X", "i", "M"} <= phases
-    xs = [e for e in doc["traceEvents"] if e["ph"] == "X"]
-    assert all(e["ts"] >= 0 and e["dur"] > 0 for e in xs)
-    by_name = {e["name"]: e for e in xs}
-    # child nests inside parent on the µs timeline
-    assert by_name["dispatch"]["ts"] >= by_name["step"]["ts"]
-    assert (by_name["dispatch"]["ts"] + by_name["dispatch"]["dur"]
-            <= by_name["step"]["ts"] + by_name["step"]["dur"] + 1e-6)
-    p = tmp_path / "trace_chrome.json"
-    write_chrome_trace(p, rec)
-    assert json.loads(p.read_text())["traceEvents"]
+def test_spans_on_the_profiler_host_plane(tmp_path):
+    """An enabled recorder's spans land by name on the host plane of a
+    jax.profiler trace, on the clock the device events use."""
+    import jax
+    import jax.numpy as jnp
+    from jax.profiler import ProfileData
+    rec = Recorder()
+    x = jnp.ones((8,))
+    with jax.profiler.trace(str(tmp_path)):
+        for i in range(2):
+            with rec.span("step", category="train", step_num=i):
+                with rec.span("data"):
+                    y = x + i
+                with rec.span("dispatch"):
+                    y = y * 2
+                with rec.span("wait"):
+                    jax.block_until_ready(y)
+    paths = list(tmp_path.rglob("*.xplane.pb"))
+    assert len(paths) == 1
+    names = [e.name for p in ProfileData.from_file(str(paths[0])).planes
+             if p.name.startswith("/host:") for line in p.lines
+             for e in line.events]
+    for span in ("step", "data", "dispatch", "wait"):
+        assert names.count(span) == 2, span
+
+
+# ---------------------------------------------------------------------------
+# Compile counter and layer scopes
+# ---------------------------------------------------------------------------
+
+def test_compile_counter_counts_one_compile_per_shape():
+    import jax
+    import jax.numpy as jnp
+    f = jax.jit(lambda x: x * 2 + 1)
+    a, b = jax.block_until_ready((jnp.ones((3,)), jnp.ones((5,))))
+    c0 = compile_counts()
+    f(a)
+    f(a)
+    c1 = compile_counts()
+    f(b)
+    c2 = compile_counts()
+    assert (c1 - c0).compiles == 1 and (c1 - c0).compile_s > 0
+    assert (c2 - c1).programs == 1
+    assert (c2 - c0) == (c2 - c1) + (c1 - c0)
+    assert (c2 - c0).to_dict()["cache_loads"] == 0
+
+
+def test_compile_counter_tells_cache_loads_from_compiles(tmp_path):
+    """A program found in the persistent cache counts as a load."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental.compilation_cache import compilation_cache
+    keys = ("jax_enable_compilation_cache", "jax_compilation_cache_dir",
+            "jax_persistent_cache_min_compile_time_secs")
+    was = {k: getattr(jax.config, k) for k in keys}
+    jax.config.update("jax_enable_compilation_cache", True)
+    jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    compilation_cache.reset_cache()
+    try:
+        f = jax.jit(lambda x: jnp.sin(x) * 3 - 1)
+        x = jax.block_until_ready(jnp.ones((7,)))
+        c0 = compile_counts()
+        f(x)
+        c1 = compile_counts()
+        jax.clear_caches()          # forget the compiled program in memory
+        f(x)
+        c2 = compile_counts()
+    finally:
+        for k, v in was.items():
+            jax.config.update(k, v)
+        compilation_cache.reset_cache()
+    assert (c1 - c0).compiles == 1 and (c1 - c0).cache_loads == 0
+    assert (c2 - c1).compiles == 0 and (c2 - c1).cache_loads == 1
+    assert (c2 - c1).cache_load_s > 0
+
+
+def test_compile_lands_as_an_event_in_the_open_span():
+    import jax
+    import jax.numpy as jnp
+    x = jax.block_until_ready(jnp.ones((11,)))
+    rec = Recorder(clock=FakeClock())
+    with use_recorder(rec):
+        with rec.span("step", step_num=0):
+            jax.jit(lambda v: v - 3)(x)
+    step = rec.find("step")[0]
+    ev = [e for e in rec.events if e["name"] == "compile"]
+    assert ev and all(e["parent_id"] == step.span_id for e in ev)
+    assert ev[-1]["attrs"]["kind"] == "backend"
+    assert ev[-1]["attrs"]["seconds"] > 0
+    # a disabled current recorder records nothing; the counter still counts
+    c0 = compile_counts()
+    jax.jit(lambda v: v - 4)(x)
+    assert (compile_counts() - c0).compiles == 1
+    assert current_recorder().events == []
+    assert CompileCounts().programs == 0
+
+
+def test_layer_scopes_name_the_train_step():
+    """A 2-layer reduced smollm train step with remat compiled on the CPU:
+    every matmul carries a layer scope, attention and mlp have both a
+    forward and a backward, remat's recompute counts as forward, and the
+    embedding, head and optimizer are scoped."""
+    import dataclasses
+    import re
+    import jax
+    import jax.numpy as jnp
+    from bench.trace.scopes import layer_keys, layer_of
+    from repro.configs import TrainConfig, get_config, reduced
+    from repro.train.step import init_train_state, make_train_step
+    cfg = dataclasses.replace(reduced(get_config("smollm-360m")),
+                              n_layers=2)
+    tcfg = TrainConfig(remat_policy="full")
+    state = jax.eval_shape(
+        lambda: init_train_state(jax.random.PRNGKey(0), cfg, tcfg))
+    batch = {"tokens": jax.ShapeDtypeStruct((2, 64), jnp.int32)}
+    txt = jax.jit(make_train_step(cfg, tcfg)).lower(
+        state, batch).compile().as_text()
+    keys = layer_keys(txt, LAYER_SCOPES)
+    dots = re.findall(r"^\s*(?:ROOT\s+)?%([\w.\-]+) = \S+ dot\(", txt,
+                      re.M)
+    assert len(dots) >= 10
+    assert all(keys[d] is not None for d in dots), \
+        [d for d in dots if keys.get(d) is None]
+    assert {("attention", "fwd"), ("attention", "bwd"), ("mlp", "fwd"),
+            ("mlp", "bwd"), ("embed", "fwd"), ("embed", "bwd"),
+            ("head", "fwd"), ("head", "bwd"),
+            ("optimizer", "fwd")} <= set(keys.values())
+    recompute = [n for n in re.findall(r'op_name="([^"]*)"', txt)
+                 if "rematted_computation" in n and "/attention/" in n]
+    assert recompute and {layer_of(n, LAYER_SCOPES)
+                          for n in recompute} == {("attention", "fwd")}
 
 
 # ---------------------------------------------------------------------------

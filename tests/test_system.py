@@ -92,6 +92,61 @@ def test_serve_driver_cli():
     assert out["generated"] == 4
 
 
+def _host_span_names(trace_dir):
+    from jax.profiler import ProfileData
+    paths = [os.path.join(d, f) for d, _, fs in os.walk(trace_dir)
+             for f in fs if f.endswith(".xplane.pb")]
+    assert len(paths) == 1, paths
+    return [e.name for p in ProfileData.from_file(paths[0]).planes
+            if p.name.startswith("/host:") for line in p.lines
+            for e in line.events]
+
+
+def test_train_driver_trace_dir_profiles_steady_steps(tmp_path):
+    """--trace-dir: JSONL spans, a profiler trace of the first three
+    steady steps with the driver's spans on its host plane, the step's
+    compiled text (the join to the layer scopes), and compile counts."""
+    trace = str(tmp_path / "trace")
+    r = _run(["-m", "repro.launch.train", "--arch", "smollm-360m",
+              "--reduced", "--steps", "6", "--batch", "2", "--seq", "32",
+              "--log-every", "2", "--trace-dir", trace])
+    assert r.returncode == 0, r.stderr[-2000:]
+    out = json.loads(r.stdout.strip().splitlines()[-1])
+    assert len(out["trace"]["xplane"]) == 1
+    names = _host_span_names(trace)
+    for span in ("step", "data", "dispatch", "wait"):
+        assert names.count(span) == 3, (span, names.count(span))
+    with open(os.path.join(trace, "step.hlo.txt")) as f:
+        assert "/attention/" in f.read()
+    assert out["compiles"]["run"]["compiles"] > 0
+    assert set(out["compiles"]["steady_steps"]) == {
+        "compiles", "compile_s", "cache_loads", "cache_load_s"}
+    with open(os.path.join(trace, "trace.jsonl")) as f:
+        recs = [json.loads(line) for line in f]
+    spans = {x["span_id"]: x for x in recs if x["type"] == "span"}
+    compiles = [x for x in recs
+                if x["type"] == "event" and x["name"] == "compile"]
+    # the warm-up step compiles the step program in its dispatch span
+    warm = [c for c in compiles if c["parent_id"] in spans
+            and spans[c["parent_id"]]["name"] == "dispatch"]
+    assert warm and all(
+        spans[spans[c["parent_id"]]["parent_id"]]["attrs"]["phase"]
+        in ("warmup", "steady") for c in warm)
+    assert not os.path.exists(os.path.join(trace, "trace_chrome.json"))
+
+
+def test_serve_driver_trace_dir_profiles_the_run(tmp_path):
+    trace = str(tmp_path / "trace")
+    r = _run(["-m", "repro.launch.serve", "--arch", "qwen2.5-3b",
+              "--reduced", "--batch", "2", "--prompt-len", "8",
+              "--gen", "4", "--trace-dir", trace])
+    assert r.returncode == 0, r.stderr[-2000:]
+    names = _host_span_names(trace)
+    assert names.count("prefill") == 1 and names.count("decode") == 1
+    assert names.count("decode_step") == 4
+    assert os.path.isfile(os.path.join(trace, "trace.jsonl"))
+
+
 def test_train_driver_fault_injection_and_resume(tmp_path):
     """Driver-level FT: die mid-run, relaunch, resume from checkpoint."""
     ckpt = str(tmp_path / "ckpt")

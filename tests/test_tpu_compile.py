@@ -5,13 +5,17 @@ TPU compiler accepts: block shapes that break the (8, 128) tiling, 1-D
 vectors whose Mosaic layout disagrees with XLA's, scalars stored to
 VMEM. Here each kernel is lowered and compiled for one chip of a
 described (not attached) v5e:2x2, so a layout the compiler refuses
-fails a test instead of a chip run. Nothing executes.
+fails a test instead of a chip run, and each is checked to carry its
+kernel name into the compiled custom-call. A cut train step checks
+that the program's layer scopes reach the ops the chip runs. Nothing
+executes.
 
 The topology is described inside a fixture, never at import: only one
 process may load the TPU compiler library at a time, and a worker that
 cannot load it skips this file's tests instead of failing collection.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -49,6 +53,14 @@ def _compiled_text(fn, *shapes):
     return jax.jit(fn).lower(*shapes).compile().as_text()
 
 
+def _kernels(txt):
+    """Names of the Pallas kernels in a compiled module: a named
+    ``pallas_call`` names its custom-call instruction (``%flash_fwd.3``)."""
+    return {m.group(1) for m in re.finditer(
+        r'%([A-Za-z_]\w*?)(?:\.\d+)? = [^\n]*custom_call_target='
+        r'"tpu_custom_call"', txt)}
+
+
 def _spec(sharding, shape, dtype):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
@@ -71,7 +83,7 @@ def test_flash_prefill_compiles(one_chip, arch):
     txt = _compiled_text(
         lambda q, k, v, qp, kp: flash_attention(q, k, v, qp, kp, spec,
                                                 block_kv=KV_BLOCK), *shapes)
-    assert "tpu_custom_call" in txt
+    assert _kernels(txt) == {"flash_fwd"}
 
 
 @pytest.mark.parametrize("arch", sorted(ATTN_WIDTHS))
@@ -87,7 +99,7 @@ def test_flash_decode_compiles(one_chip, arch):
     txt = _compiled_text(
         lambda q, k, v, qp, kp: flash_attention(q, k, v, qp, kp, spec,
                                                 block_kv=KV_BLOCK), *shapes)
-    assert "tpu_custom_call" in txt
+    assert _kernels(txt) == {"flash_fwd"}
 
 
 def test_ssd_scan_compiles_mamba2_widths(one_chip):
@@ -100,7 +112,7 @@ def test_ssd_scan_compiles_mamba2_widths(one_chip):
               _spec(one_chip, (b, l, g, n), jnp.bfloat16),
               _spec(one_chip, (h,), jnp.float32)]
     txt = _compiled_text(lambda *a: ssd_scan(*a, chunk=256), *shapes)
-    assert "tpu_custom_call" in txt
+    assert _kernels(txt) == {"ssd_scan_fwd"}
 
 
 LEAF = (4096, 1024)    # one gradient leaf on the int8 wire
@@ -109,11 +121,59 @@ LEAF = (4096, 1024)    # one gradient leaf on the int8 wire
 def test_quantize_int8_compiles(one_chip):
     txt = _compiled_text(quantize_int8_pallas,
                          _spec(one_chip, LEAF, jnp.float32))
-    assert "tpu_custom_call" in txt
+    assert _kernels(txt) == {"int8_absmax", "int8_quantize"}
 
 
 def test_dequantize_int8_compiles(one_chip):
     txt = _compiled_text(dequantize_int8_pallas,
                          _spec(one_chip, LEAF, jnp.int8),
                          _spec(one_chip, (), jnp.float32))
-    assert "tpu_custom_call" in txt
+    assert _kernels(txt) == {"int8_dequantize"}
+
+
+def test_train_step_ops_carry_layer_scopes(one_chip, monkeypatch):
+    """smollm-360m's train step cut to 2 layers (batch 2 x 512, remat
+    full, Pallas on) compiled for one v5e: every fusion that computes
+    carries a layer scope, what carries none only moves the layer scan's
+    data, and the flash kernel's two calls (forward and remat's
+    recompute) are attention's forward."""
+    import dataclasses
+    from bench.trace.scopes import MOVES_DATA, layer_keys
+    from repro.configs import TrainConfig, get_config
+    from repro.kernels import ops
+    from repro.obs import LAYER_SCOPES
+    from repro.train.step import init_train_state, make_train_step
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    cfg = dataclasses.replace(get_config("smollm-360m"), n_layers=2)
+    tcfg = TrainConfig(remat_policy="full")
+    state = jax.eval_shape(
+        lambda: init_train_state(jax.random.PRNGKey(0), cfg, tcfg))
+    state = jax.tree.map(lambda x: _spec(one_chip, x.shape, x.dtype), state)
+    batch = {"tokens": _spec(one_chip, (2, 512), jnp.int32)}
+    txt = jax.jit(make_train_step(cfg, tcfg)).lower(
+        state, batch).compile().as_text()
+    keys = layer_keys(txt, LAYER_SCOPES)
+    body, opcodes, fusions = None, {}, []
+    for line in txt.splitlines():
+        head = re.match(r"^(?:ENTRY\s+)?%([\w.\-]+) ", line)
+        if head:
+            body = head.group(1)
+            continue
+        m = re.match(r"^\s*(?:ROOT\s+)?%([\w.\-]+) = (?:\(.*?\)|\S+) "
+                     r"([\w\-]+)\(", line)
+        if m:
+            opcodes.setdefault(body, set()).add(m.group(2))
+            calls = re.search(r"\bcalls=%([\w.\-]+)", line)
+            if m.group(2) == "fusion" and calls:
+                fusions.append((m.group(1), calls.group(1)))
+    computing = [f for f, c in fusions if opcodes[c] - MOVES_DATA]
+    assert len(computing) > 50
+    assert all(keys.get(f) is not None for f in computing), \
+        [f for f in computing if keys.get(f) is None]
+    assert all(opcodes[c] <= MOVES_DATA for f, c in fusions
+               if keys.get(f) is None)
+    flash = [n for n in keys if re.fullmatch(r"flash_fwd(\.\d+)?", n)]
+    assert len(flash) == 2
+    assert {keys[n] for n in flash} == {("attention", "fwd")}
+    assert {("attention", "bwd"), ("mlp", "fwd"), ("mlp", "bwd"),
+            ("head", "bwd"), ("optimizer", "fwd")} <= set(keys.values())

@@ -84,26 +84,59 @@ def test_flash_attention_property(B, S, Hkv, hd, causal):
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=3e-5)
 
 
-def test_flash_attention_grads_match_ref():
-    """The kernel's custom VJP (blockwise jnp recompute) gives the
+def _ring(n, shift):
+    """Slot positions of a ring buffer written ``shift`` slots past its
+    start: out of order, so blocks hold non-contiguous positions."""
+    return (jnp.arange(n) + shift) % n
+
+
+GRAD_CASES = {
+    # B, Sq, Skv, Hq, Hkv, hd, causal, window, softcap, kv positions
+    "causal_window": (1, 96, 96, 4, 2, 16, True, 40, 0.0, None),
+    "causal_g3_ragged": (1, 640, 640, 3, 1, 64, True, 0, 0.0, None),
+    # q block 0 holds positions 1..512 and kv block 1 starts at 512: the
+    # pair shares one attendable pair, which block skipping must keep
+    "causal_g1_offset": (2, 1023, 1024, 2, 2, 32, True, 0, 0.0, None),
+    # blocks of 512: (q2, k0) and (q3, k1) share one pair each at the
+    # window's edge, (q3, k0) none
+    "window_skips_blocks": (1, 1600, 1600, 3, 1, 16, True, 514, 0.0, None),
+    "softcap": (1, 600, 600, 2, 1, 32, True, 0, 30.0, None),
+    "noncausal_cross": (2, 200, 700, 4, 2, 16, False, 0, 0.0, None),
+    "hd192": (1, 300, 300, 2, 2, 192, True, 0, 0.0, None),
+    "ring_positions": (1, 1024, 1024, 2, 1, 16, True, 400, 0.0, 300),
+}
+# worst |grad - ref|: 2e-5 for float32 operands; for bfloat16 ones (P and
+# dS rounded to bf16, unit roundoff 2^-9) 2^-6 of the largest |ref|
+GRAD_TOL = {jnp.float32: lambda ref: 2e-5,
+            jnp.bfloat16: lambda ref: 2 ** -6 * np.abs(ref).max()}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("case", sorted(GRAD_CASES))
+def test_flash_attention_grads_match_ref(case, dtype):
+    """The kernel's custom VJP (the Pallas backward) gives the
     reference's gradients — what a training step on the chip uses."""
-    B, S, Hq, Hkv, hd = 1, 96, 4, 2, 16
-    q, k, v = _qkv(B, S, S, Hq, Hkv, hd, jnp.float32)
-    pos = jnp.arange(S)
-    spec = AttnSpec(causal=True, window=40)
-    w = jax.random.normal(jax.random.fold_in(KEY, 7), (B, S, Hq, hd))
+    B, Sq, Skv, Hq, Hkv, hd, causal, window, cap, ring = GRAD_CASES[case]
+    q, k, v = _qkv(B, Sq, Skv, Hq, Hkv, hd, dtype)
+    kv_pos = jnp.arange(Skv) if ring is None else _ring(Skv, ring)
+    q_pos = jnp.arange(Skv - Sq, Skv)
+    spec = AttnSpec(causal=causal, window=window, logit_softcap=cap)
+    w = jax.random.normal(jax.random.fold_in(KEY, 7), (B, Sq, Hq, hd))
 
     def loss(fn):
-        return lambda q, k, v: jnp.sum(fn(q, k, v) * w)
+        return lambda q, k, v: jnp.sum(fn(q, k, v).astype(jnp.float32) * w)
 
-    got = jax.grad(loss(lambda q, k, v: flash_attention(
-        q, k, v, pos, pos, spec, block_q=32, block_kv=32, interpret=True)),
-        argnums=(0, 1, 2))(q, k, v)
-    want = jax.grad(loss(lambda q, k, v: attention_ref(q, k, v, pos, pos,
-                                                        spec)),
-                    argnums=(0, 1, 2))(q, k, v)
+    got = jax.jit(jax.grad(loss(lambda q, k, v: flash_attention(
+        q, k, v, q_pos, kv_pos, spec, block_q=128, block_kv=128,
+        interpret=True)), argnums=(0, 1, 2)))(q, k, v)
+    f32 = [x.astype(jnp.float32) for x in (q, k, v)]
+    want = jax.jit(jax.grad(loss(lambda q, k, v: attention_ref(
+        q, k, v, q_pos, kv_pos, spec)), argnums=(0, 1, 2)))(*f32)
     for g, r in zip(got, want):
-        np.testing.assert_allclose(np.asarray(g), np.asarray(r), atol=2e-5)
+        assert g.dtype == dtype
+        r = np.asarray(r)
+        err = np.abs(np.asarray(g, np.float32) - r).max()
+        assert err <= GRAD_TOL[dtype](r), (err, case)
 
 
 def test_blockwise_jnp_matches_naive():

@@ -102,6 +102,30 @@ def test_flash_decode_compiles(one_chip, arch):
     assert _kernels(txt) == {"flash_fwd"}
 
 
+@pytest.mark.parametrize("arch", sorted(ATTN_WIDTHS))
+def test_flash_grad_compiles(one_chip, arch):
+    """Training's path: the forward, and the backward's log-sum-exp, dQ
+    and dK/dV kernels, all accepted by Mosaic at real widths."""
+    hq, hkv, hd = ATTN_WIDTHS[arch]
+    spec = AttnSpec(causal=True)
+    shapes = [_spec(one_chip, (1, SEQ, hq, hd), jnp.bfloat16),
+              _spec(one_chip, (1, SEQ, hkv, hd), jnp.bfloat16),
+              _spec(one_chip, (1, SEQ, hkv, hd), jnp.bfloat16),
+              _spec(one_chip, (SEQ,), jnp.int32),
+              _spec(one_chip, (SEQ,), jnp.int32)]
+
+    def loss(q, k, v, qp, kp):
+        # a scope under the transforms, as the model's layers give, so
+        # the kernels keep their own names
+        with jax.named_scope("attention"):
+            o = flash_attention(q, k, v, qp, kp, spec, block_kv=KV_BLOCK)
+        return jnp.sum(o.astype(jnp.float32))
+
+    txt = _compiled_text(jax.grad(loss, argnums=(0, 1, 2)), *shapes)
+    assert _kernels(txt) == {"flash_fwd", "flash_bwd_lse", "flash_bwd_dq",
+                             "flash_bwd_dkv"}
+
+
 def test_ssd_scan_compiles_mamba2_widths(one_chip):
     # mamba2-370m: 32 heads of 64, state 128, one B/C group, chunk 256
     b, l, h, p, g, n = 1, SEQ, 32, 64, 1, 128
@@ -135,8 +159,8 @@ def test_train_step_ops_carry_layer_scopes(one_chip, monkeypatch):
     """smollm-360m's train step cut to 2 layers (batch 2 x 512, remat
     full, Pallas on) compiled for one v5e: every fusion that computes
     carries a layer scope, what carries none only moves the layer scan's
-    data, and the flash kernel's two calls (forward and remat's
-    recompute) are attention's forward."""
+    data, the flash kernel's two calls (forward and remat's recompute)
+    are attention's forward, and the backward's kernels its backward."""
     import dataclasses
     from bench.trace.scopes import MOVES_DATA, layer_keys
     from repro.configs import TrainConfig, get_config
@@ -175,5 +199,9 @@ def test_train_step_ops_carry_layer_scopes(one_chip, monkeypatch):
     flash = [n for n in keys if re.fullmatch(r"flash_fwd(\.\d+)?", n)]
     assert len(flash) == 2
     assert {keys[n] for n in flash} == {("attention", "fwd")}
+    bwd = [n for n in keys if re.fullmatch(r"flash_bwd_\w+?(\.\d+)?", n)]
+    assert {re.sub(r"\.\d+$", "", n) for n in bwd} == {
+        "flash_bwd_lse", "flash_bwd_dq", "flash_bwd_dkv"}
+    assert {keys[n] for n in bwd} == {("attention", "bwd")}
     assert {("attention", "bwd"), ("mlp", "fwd"), ("mlp", "bwd"),
             ("head", "bwd"), ("optimizer", "fwd")} <= set(keys.values())

@@ -1,0 +1,15 @@
+"""Device self time per step of the SSD chunked scan's forward work: the
+ops under the program's ``ssd_scan`` scope that are not its backward, so
+the forward and remat's recompute, the Pallas kernel's calls included
+(``bench.trace.scopes``). Nothing to read where the program has no
+``ssd_scan`` scope."""
+from bench.trace import scopes
+
+UNIT, LAYER, MOVES, SOURCE = "ms", "ssd scan", "train_tokens_per_s", \
+    "device_trace"
+
+
+def read(ctx):
+    if "ssd_scan" not in (scopes.layer_scopes() or ()):
+        return None
+    return scopes.read(ctx, [("ssd_scan", "fwd")])

@@ -24,7 +24,8 @@ from repro.models.layers import Param, Params, dense, init_dense, make_param
 
 
 # ---------------------------------------------------------------------------
-# Reference chunked SSD (pure jnp; oracle for the Pallas kernel)
+# Reference chunked SSD (pure jnp; oracle for the Pallas kernel, and the
+# recompute its custom VJP differentiates)
 # ---------------------------------------------------------------------------
 
 def segsum(x: jax.Array) -> jax.Array:
@@ -69,16 +70,22 @@ def ssd_reference(x: jax.Array, dt: jax.Array, A: jax.Array, B: jax.Array,
     Bh = jnp.repeat(Bc, rep, axis=3)                     # [b, c, q, h, n]
     Ch = jnp.repeat(Cc, rep, axis=3)
 
+    # Every decay below is the exp of a sum of dt·A <= 0, formed as a sum
+    # and never as a quotient of exponentials: each lies in [0, 1] and its
+    # gradient exp(s)·g stays finite however far exp(s) underflows.
+
     # --- intra-chunk (quadratic in chunk len, MXU-friendly) ---------------
+    # L[q, k] = exp(sum_{k<j<=q} dtA) below the diagonal; exp(-inf) = 0 above
     Ls = jnp.exp(segsum(dtAc.transpose(0, 1, 3, 2)))     # [b, c, h, q, q]
-    scores = jnp.einsum("bcqhn,bckhn->bchqk", Ch, Bh) * jnp.where(
-        jnp.isfinite(Ls), Ls, 0.0)
+    scores = jnp.einsum("bcqhn,bckhn->bchqk", Ch, Bh) * Ls
     y_intra = jnp.einsum("bchqk,bckh,bckhp->bcqhp", scores, dtc, xc)
 
     # --- chunk states ------------------------------------------------------
-    decay_out = jnp.exp(dtAc[..., ::-1, :].cumsum(axis=2))[..., ::-1, :]
-    # decay from position q to end of chunk: exp(sum_{k>q} dtA) — shift by one
-    decay_states = decay_out / jnp.exp(dtAc)             # exp(sum_{k>q})
+    # decay from position q to the end of its chunk, exp(sum_{k>q} dtA):
+    # the inclusive reverse cumulative sum shifted up by one position
+    rev = dtAc[..., ::-1, :].cumsum(axis=2)[..., ::-1, :]  # sum_{k>=q}
+    after = jnp.pad(rev[:, :, 1:], ((0, 0), (0, 0), (0, 1), (0, 0)))
+    decay_states = jnp.exp(after)                        # [b, c, q, h]
     states = jnp.einsum("bcqhn,bcqh,bcqh,bcqhp->bchpn",
                         Bh, dtc, decay_states, xc)       # [b, c, h, p, n]
 
@@ -97,6 +104,7 @@ def ssd_reference(x: jax.Array, dt: jax.Array, A: jax.Array, B: jax.Array,
          chunk_decay.transpose(1, 0, 2)))
     prev_states = prev_states.transpose(1, 0, 2, 3, 4)   # [b, c, h, p, n]
 
+    # decay from the chunk's start to q: exp(sum_{k<=q} dtA)
     decay_in = jnp.exp(dtAc.cumsum(axis=2))              # [b, c, q, h]
     y_inter = jnp.einsum("bcqhn,bcqh,bchpn->bcqhp", Ch, decay_in,
                          prev_states.astype(Ch.dtype))
@@ -219,10 +227,14 @@ def mamba2_forward(params: Params, x: jax.Array, cfg: ModelConfig,
             dt = jnp.pad(dt, ((0, 0), (0, pad), (0, 0)))
             Bh = jnp.pad(Bh, ((0, 0), (0, pad), (0, 0), (0, 0)))
             Ch = jnp.pad(Ch, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        y, final_state = ops.ssd_chunked(
-            xh, dt, A, Bh, Ch, params["D"].value, chunk=s.chunk_size,
-            fallback=lambda x_, dt_, A_, B__, C__, D_, chunk: ssd_reference(
-                x_, dt_, A_, B__, C__, D_, chunk=chunk, return_state=True))
+        # the scan (forward, remat's recompute, backward) has a layer
+        # scope of its own inside the block's ``ssd``
+        with jax.named_scope("ssd_scan"):
+            y, final_state = ops.ssd_chunked(
+                xh, dt, A, Bh, Ch, params["D"].value, chunk=s.chunk_size,
+                fallback=lambda x_, dt_, A_, B__, C__, D_, chunk:
+                ssd_reference(x_, dt_, A_, B__, C__, D_, chunk=chunk,
+                              return_state=True))
         y = y[:, :L].reshape(B_, L, d_in)
         new_cache = (conv_tail, final_state)
     else:

@@ -52,9 +52,10 @@ from typing import Any, Callable, Dict, List, Optional
 SYNC_POLICIES = ("none", "boundary")
 
 # The program's layer scopes (jax.named_scope names): a device op belongs
-# to the last of these in its op_name path.
-LAYER_SCOPES = ("embed", "attention", "mlp", "moe", "ssd", "head",
-                "optimizer")
+# to the last of these in its op_name path, so ``ssd_scan`` (the SSD
+# chunked scan) takes its ops from the ``ssd`` block around it.
+LAYER_SCOPES = ("embed", "attention", "mlp", "moe", "ssd", "ssd_scan",
+                "head", "optimizer")
 
 
 @dataclass

@@ -210,6 +210,70 @@ def test_ssd_scan_grads_match_ref():
                                    atol=1e-4, rtol=1e-4)
 
 
+def _ssd_quadratic(x, dt, A, B, C, D):
+    """Plain SSD, its quadratic form: y_t = sum_{s<=t} C_t·B_s
+    exp(sum_{s<k<=t} dt_k A) dt_s x_s + D x_t, and the final state
+    sum_s exp(sum_{s<k<=T} dt_k A) dt_s x_s B_sᵀ. No chunks."""
+    l, rep = x.shape[1], x.shape[2] // B.shape[2]
+    cs = jnp.cumsum(dt * A, axis=1)
+    seg = cs[:, :, None, :] - cs[:, None, :, :]              # [b, t, s, h]
+    causal = jnp.tril(jnp.ones((l, l), bool))[None, :, :, None]
+    decay = jnp.exp(jnp.where(causal, seg, -jnp.inf))
+    Bh, Ch = jnp.repeat(B, rep, axis=2), jnp.repeat(C, rep, axis=2)
+    w = jnp.einsum("bthn,bshn->btsh", Ch, Bh) * decay * dt[:, None]
+    y = jnp.einsum("btsh,bshp->bthp", w, x) + x * D[:, None]
+    st = jnp.einsum("bsh,bshp,bshn->bhpn", decay[:, -1] * dt, x, Bh)
+    return y, st
+
+
+@pytest.mark.parametrize("case", ["spikes", "saturated"])
+def test_ssd_strong_decay_matches_quadratic(case):
+    """Past dt·|A| ≈ 44 at one position, exp(dt·A)² underflows: a
+    backward that divides by exp(dt·A) then gives inf/NaN gradients.
+    Here one chunk of 256 sums dt·A below −100 (``spikes``: dt·A = −64
+    every 37th position; ``saturated``: −32 to −80 everywhere, chunk
+    sums near −1.4e4). The chunked reference and the scan (its custom
+    VJP) must match the quadratic form, computed in float64, in the
+    values and in the gradients of every input."""
+    b, l, h, p, g, n, chunk = 1, 512, 2, 16, 1, 16, 256
+    ks = jax.random.split(KEY, 6)
+    x = jax.random.normal(ks[0], (b, l, h, p)) * 0.5
+    A = -jnp.array([1.0, 16.0])
+    if case == "spikes":
+        dt = jax.nn.softplus(jax.random.normal(ks[1], (b, l, h))) * 0.2
+        dt = dt.at[:, ::37].set(4.0)
+    else:
+        dt = jax.random.uniform(ks[1], (b, l, h), minval=2.0, maxval=5.0)
+    B = jax.random.normal(ks[2], (b, l, g, n)) * 0.3
+    C = jax.random.normal(ks[3], (b, l, g, n)) * 0.3
+    D = jnp.ones((h,))
+    wy = jax.random.normal(ks[4], (b, l, h, p))
+    args = (x, dt, A, B, C, D)
+    assert float(jnp.min((dt * A).reshape(b, -1, chunk, h).sum(2))) < -100
+
+    def loss(fn):
+        def f(*a):
+            y, st_final = fn(*a)
+            return jnp.sum(y * wy) + jnp.sum(st_final)
+        return f
+
+    with jax.enable_x64(True):
+        a64 = [jnp.asarray(np.asarray(a), jnp.float64) for a in args]
+        want = [np.asarray(v) for v in (
+            *_ssd_quadratic(*a64),
+            *jax.grad(loss(_ssd_quadratic), argnums=tuple(range(6)))(*a64))]
+    for fn in (lambda *a: ssd_ref(*a, chunk=chunk),
+               lambda *a: ssd_scan(*a, chunk=chunk, interpret=True)):
+        got = (*fn(*args), *jax.grad(loss(fn), argnums=tuple(range(6)))(*args))
+        for gv, r in zip(got, want):
+            gv = np.asarray(gv, np.float64)
+            assert np.all(np.isfinite(gv))
+            # float32 cumulative sums of dt·A reach 1.5e4, where one ulp
+            # is 1e-3: an exponent off by δ is a decay off by δ relative
+            np.testing.assert_allclose(gv, r, rtol=0,
+                                       atol=1e-3 * np.max(np.abs(r)))
+
+
 # ---------------------------------------------------------------------------
 # int8 wire codec: Pallas kernels vs the jnp reference in repro.dist
 # ---------------------------------------------------------------------------

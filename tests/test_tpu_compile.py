@@ -139,6 +139,29 @@ def test_ssd_scan_compiles_mamba2_widths(one_chip):
     assert _kernels(txt) == {"ssd_scan_fwd"}
 
 
+def test_ssd_scan_grad_compiles_mamba2_widths(one_chip):
+    """Training's path at mamba2-370m widths, one layer of 2048
+    positions: the Pallas forward and the custom VJP's jnp backward."""
+    b, l, h, p, g, n = 1, SEQ, 32, 64, 1, 128
+    shapes = [_spec(one_chip, (b, l, h, p), jnp.bfloat16),
+              _spec(one_chip, (b, l, h), jnp.float32),
+              _spec(one_chip, (h,), jnp.float32),
+              _spec(one_chip, (b, l, g, n), jnp.bfloat16),
+              _spec(one_chip, (b, l, g, n), jnp.bfloat16),
+              _spec(one_chip, (h,), jnp.float32)]
+
+    def loss(*a):
+        with jax.named_scope("ssd_scan"):
+            y, st = ssd_scan(*a, chunk=256)
+        return jnp.sum(y.astype(jnp.float32)) + jnp.sum(st.astype(
+            jnp.float32))
+
+    # the value too: a gradient alone does not need the forward's results
+    txt = _compiled_text(jax.value_and_grad(loss, argnums=tuple(range(6))),
+                         *shapes)
+    assert _kernels(txt) == {"ssd_scan_fwd"}
+
+
 LEAF = (4096, 1024)    # one gradient leaf on the int8 wire
 
 
@@ -205,3 +228,31 @@ def test_train_step_ops_carry_layer_scopes(one_chip, monkeypatch):
     assert {keys[n] for n in bwd} == {("attention", "bwd")}
     assert {("attention", "bwd"), ("mlp", "fwd"), ("mlp", "bwd"),
             ("head", "bwd"), ("optimizer", "fwd")} <= set(keys.values())
+
+
+def test_mamba2_step_scan_carries_its_scope(one_chip, monkeypatch):
+    """mamba2-370m's train step cut to 2 layers (batch 1 x 512, remat
+    full, Pallas on) compiled for one v5e: the scan kernel's two calls a
+    layer (forward and remat's recompute) are ``ssd_scan``'s forward, the
+    custom VJP's backward is its backward, and the projections ``ssd``'s."""
+    import dataclasses
+    from bench.trace.scopes import layer_keys
+    from repro.configs import TrainConfig, get_config
+    from repro.kernels import ops
+    from repro.obs import LAYER_SCOPES
+    from repro.train.step import init_train_state, make_train_step
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    cfg = dataclasses.replace(get_config("mamba2-370m"), n_layers=2)
+    tcfg = TrainConfig(remat_policy="full")
+    state = jax.eval_shape(
+        lambda: init_train_state(jax.random.PRNGKey(0), cfg, tcfg))
+    state = jax.tree.map(lambda x: _spec(one_chip, x.shape, x.dtype), state)
+    batch = {"tokens": _spec(one_chip, (1, 512), jnp.int32)}
+    txt = jax.jit(make_train_step(cfg, tcfg)).lower(
+        state, batch).compile().as_text()
+    keys = layer_keys(txt, LAYER_SCOPES)
+    scan = [k for k in keys if re.fullmatch(r"ssd_scan_fwd(\.\d+)?", k)]
+    assert len(scan) == 2
+    assert {keys[k] for k in scan} == {("ssd_scan", "fwd")}
+    assert {("ssd_scan", "bwd"), ("ssd", "fwd"), ("ssd", "bwd")} <= set(
+        keys.values())

@@ -180,34 +180,46 @@ def test_ssd_scan_matches_ref(case, dtype):
                                np.asarray(str_, np.float32), atol=tol)
 
 
-def test_ssd_scan_grads_match_ref():
-    """The scan's custom VJP differentiates the reference: gradients of
-    a loss over y and the final state match the reference's own."""
-    b, l, h, p, g, n, chunk = 1, 64, 2, 8, 1, 8, 32
-    ks = jax.random.split(KEY, 6)
-    x = jax.random.normal(ks[0], (b, l, h, p)) * 0.5
+@pytest.mark.parametrize("case", SSD_CASES)
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_ssd_scan_grads_match_ref(case, dtype):
+    """The scan's custom VJP (the Pallas backward) gives the reference's
+    gradients of all six inputs, for cotangents on y and on the final
+    state: float32 to 1e-4, bfloat16 to a share of the largest reference
+    gradient (its MXU operands are bf16)."""
+    b, l, h, p, g, n, chunk = case
+    ks = jax.random.split(KEY, 7)
+    x = (jax.random.normal(ks[0], (b, l, h, p)) * 0.5).astype(dtype)
     dt = jax.nn.softplus(jax.random.normal(ks[1], (b, l, h))) * 0.2
     A = -jnp.exp(jax.random.normal(ks[2], (h,)) * 0.3)
-    B = jax.random.normal(ks[3], (b, l, g, n)) * 0.3
-    C = jax.random.normal(ks[4], (b, l, g, n)) * 0.3
-    D = jnp.ones((h,))
+    B = (jax.random.normal(ks[3], (b, l, g, n)) * 0.3).astype(dtype)
+    C = (jax.random.normal(ks[4], (b, l, g, n)) * 0.3).astype(dtype)
+    D = jnp.full((h,), 0.7)
     wy = jax.random.normal(ks[5], (b, l, h, p))
+    ws = jax.random.normal(ks[6], (b, h, p, n))
 
     def loss(fn):
         def f(*args):
             y, st_final = fn(*args)
-            return jnp.sum(y * wy) + jnp.sum(st_final)
+            return (jnp.sum(y.astype(jnp.float32) * wy)
+                    + jnp.sum(st_final.astype(jnp.float32) * ws))
         return f
 
     args = (x, dt, A, B, C, D)
-    got = jax.grad(loss(lambda *a: ssd_scan(*a, chunk=chunk,
-                                            interpret=True)),
-                   argnums=tuple(range(6)))(*args)
-    want = jax.grad(loss(lambda *a: ssd_ref(*a, chunk=chunk)),
-                    argnums=tuple(range(6)))(*args)
-    for gv, r in zip(got, want):
-        np.testing.assert_allclose(np.asarray(gv), np.asarray(r),
-                                   atol=1e-4, rtol=1e-4)
+    got = jax.jit(jax.grad(loss(lambda *a: ssd_scan(
+        *a, chunk=chunk, interpret=True)), argnums=tuple(range(6))))(*args)
+    want = jax.jit(jax.grad(loss(lambda *a: ssd_ref(*a, chunk=chunk)),
+                            argnums=tuple(range(6))))(
+        *[a.astype(jnp.float32) for a in args])
+    for gv, r, a in zip(got, want, args):
+        assert gv.dtype == a.dtype
+        r = np.asarray(r)
+        if dtype == jnp.float32:
+            np.testing.assert_allclose(np.asarray(gv), r, atol=1e-4,
+                                       rtol=1e-4)
+        else:
+            err = np.abs(np.asarray(gv, np.float32) - r).max()
+            assert err <= GRAD_TOL[dtype](r), (err, case)
 
 
 def _ssd_quadratic(x, dt, A, B, C, D):
@@ -259,12 +271,14 @@ def test_ssd_strong_decay_matches_quadratic(case):
 
     with jax.enable_x64(True):
         a64 = [jnp.asarray(np.asarray(a), jnp.float64) for a in args]
-        want = [np.asarray(v) for v in (
-            *_ssd_quadratic(*a64),
-            *jax.grad(loss(_ssd_quadratic), argnums=tuple(range(6)))(*a64))]
+        want = [np.asarray(v) for v in jax.jit(lambda *a: (
+            *_ssd_quadratic(*a),
+            *jax.grad(loss(_ssd_quadratic), argnums=tuple(range(6)))(*a)))(
+                *a64)]
     for fn in (lambda *a: ssd_ref(*a, chunk=chunk),
                lambda *a: ssd_scan(*a, chunk=chunk, interpret=True)):
-        got = (*fn(*args), *jax.grad(loss(fn), argnums=tuple(range(6)))(*args))
+        got = jax.jit(lambda *a: (*fn(*a), *jax.grad(
+            loss(fn), argnums=tuple(range(6)))(*a)))(*args)
         for gv, r in zip(got, want):
             gv = np.asarray(gv, np.float64)
             assert np.all(np.isfinite(gv))

@@ -139,16 +139,17 @@ def test_ssd_scan_compiles_mamba2_widths(one_chip):
     assert _kernels(txt) == {"ssd_scan_fwd"}
 
 
-def test_ssd_scan_grad_compiles_mamba2_widths(one_chip):
-    """Training's path at mamba2-370m widths, one layer of 2048
-    positions: the Pallas forward and the custom VJP's jnp backward."""
-    b, l, h, p, g, n = 1, SEQ, 32, 64, 1, 128
-    shapes = [_spec(one_chip, (b, l, h, p), jnp.bfloat16),
-              _spec(one_chip, (b, l, h), jnp.float32),
-              _spec(one_chip, (h,), jnp.float32),
-              _spec(one_chip, (b, l, g, n), jnp.bfloat16),
-              _spec(one_chip, (b, l, g, n), jnp.bfloat16),
-              _spec(one_chip, (h,), jnp.float32)]
+def _ssd_grad_text(sharding, h, p, g, n):
+    """Training's path through the scan compiled, one layer of 2048
+    positions at the given widths: the value too, since a gradient alone
+    does not need the forward's results."""
+    b, l = 1, SEQ
+    shapes = [_spec(sharding, (b, l, h, p), jnp.bfloat16),
+              _spec(sharding, (b, l, h), jnp.float32),
+              _spec(sharding, (h,), jnp.float32),
+              _spec(sharding, (b, l, g, n), jnp.bfloat16),
+              _spec(sharding, (b, l, g, n), jnp.bfloat16),
+              _spec(sharding, (h,), jnp.float32)]
 
     def loss(*a):
         with jax.named_scope("ssd_scan"):
@@ -156,10 +157,32 @@ def test_ssd_scan_grad_compiles_mamba2_widths(one_chip):
         return jnp.sum(y.astype(jnp.float32)) + jnp.sum(st.astype(
             jnp.float32))
 
-    # the value too: a gradient alone does not need the forward's results
-    txt = _compiled_text(jax.value_and_grad(loss, argnums=tuple(range(6))),
-                         *shapes)
-    assert _kernels(txt) == {"ssd_scan_fwd"}
+    return _compiled_text(
+        jax.value_and_grad(loss, argnums=tuple(range(6))), *shapes)
+
+
+SSD_KERNELS = {"ssd_scan_fwd", "ssd_scan_bwd_states", "ssd_scan_bwd_grads"}
+
+
+def test_ssd_scan_grad_compiles_mamba2_widths(one_chip):
+    """mamba2-370m (32 heads of 64, state 128, one B/C group, chunk 256):
+    the Pallas forward and the backward's two Pallas kernels. The
+    benchmark's roofline readers take the gradients call for the
+    backward's shapes and never for the forward's."""
+    from bench.metrics import ssd_scan_bwd_roofline, ssd_scan_fwd_roofline
+    txt = _ssd_grad_text(one_chip, 32, 64, 1, 128)
+    assert _kernels(txt) == SSD_KERNELS
+    grads = [line.strip() for line in txt.splitlines()
+             if re.match(r"\s*%ssd_scan_bwd_grads[.\d]* = ", line)]
+    assert len(grads) == 1
+    assert ssd_scan_bwd_roofline.call_shape(grads[0]) == (
+        1, SEQ, 32, 64, 1, 128, 2)
+    assert ssd_scan_fwd_roofline.call_shape(grads[0]) is None
+
+
+def test_ssd_scan_grad_compiles_zamba2_widths(one_chip):
+    """zamba2-1.2b's SSD blocks: 64 heads of 64, state 64, one group."""
+    assert _kernels(_ssd_grad_text(one_chip, 64, 64, 1, 64)) == SSD_KERNELS
 
 
 LEAF = (4096, 1024)    # one gradient leaf on the int8 wire
@@ -234,7 +257,8 @@ def test_mamba2_step_scan_carries_its_scope(one_chip, monkeypatch):
     """mamba2-370m's train step cut to 2 layers (batch 1 x 512, remat
     full, Pallas on) compiled for one v5e: the scan kernel's two calls a
     layer (forward and remat's recompute) are ``ssd_scan``'s forward, the
-    custom VJP's backward is its backward, and the projections ``ssd``'s."""
+    custom VJP's backward kernels its backward, and the projections
+    ``ssd``'s."""
     import dataclasses
     from bench.trace.scopes import layer_keys
     from repro.configs import TrainConfig, get_config
@@ -256,3 +280,7 @@ def test_mamba2_step_scan_carries_its_scope(one_chip, monkeypatch):
     assert {keys[k] for k in scan} == {("ssd_scan", "fwd")}
     assert {("ssd_scan", "bwd"), ("ssd", "fwd"), ("ssd", "bwd")} <= set(
         keys.values())
+    bwd = [k for k in keys if re.fullmatch(r"ssd_scan_bwd_\w+?(\.\d+)?", k)]
+    assert {re.sub(r"\.\d+$", "", k) for k in bwd} == {
+        "ssd_scan_bwd_states", "ssd_scan_bwd_grads"}
+    assert {keys[k] for k in bwd} == {("ssd_scan", "bwd")}

@@ -19,7 +19,7 @@ def main() -> None:
                     help="fewer seeds/generations (CI-scale)")
     ap.add_argument("--only", default="",
                     help="comma list: table2..table6,fig7,fig8,roofline,"
-                         "measured,planner,overlap,elastic,ft,trace")
+                         "measured,planner,elastic,ft,trace")
     args = ap.parse_args()
 
     from benchmarks.common import ART, emit
@@ -52,11 +52,6 @@ def main() -> None:
         cmd = ["benchmarks.plan", "--validate"]
         cmd += ["--quick"] if args.quick else []
         return _pool_subprocess(cmd, "benchmarks/PLANNER.md")
-
-    def overlap():
-        cmd = ["benchmarks.overlap"]
-        cmd += ["--dry-run"] if args.quick else []
-        return _pool_subprocess(cmd, "benchmarks/OVERLAP.md")
 
     def elastic():
         cmd = ["benchmarks.elastic"]
@@ -96,7 +91,6 @@ def main() -> None:
     jobs = {
         "measured": measured,
         "planner": planner,
-        "overlap": overlap,
         "elastic": elastic,
         "ft": ft,
         "trace": trace,

@@ -141,9 +141,9 @@ def _overhead(mesh, step, state, batch, rounds=OVERHEAD_ROUNDS,
 
     rec = Recorder(enabled=False)
 
-    # state is held FIXED across all blocks (like benchmarks.overlap's
-    # timing loop): every call runs the identical program on identical
-    # values, so state evolution cannot bias one side's step times
+    # state is held FIXED across all blocks: every call runs the
+    # identical program on identical values, so state evolution cannot
+    # bias one side's step times
     def plain_block():
         t0 = time.perf_counter()
         for _ in range(block):

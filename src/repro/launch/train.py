@@ -350,9 +350,11 @@ def _train(args, rec, profile: _StepProfile):
         same ``param_pspecs`` resolution."""
         if path == "sharded":
             # Real shard_map step: params enter sharded per the
-            # strategy's logical-rule pspecs, are all-gathered in-body,
-            # and gradients all-reduce through the compressed collective
-            # (see repro.train.step.make_sharded_train_step).
+            # strategy's logical-rule pspecs; tensor-parallel dims stay
+            # local (each model rank computes its slice), the rest are
+            # gathered in-body, and gradients all-reduce through the
+            # compressed collective (repro.train.step.
+            # make_sharded_train_step).
             skel = jax.eval_shape(
                 lambda: init_sharded_train_state(key, cfg, tcfg, mesh))
             st_specs = sharded_state_specs(cfg, tcfg, mesh, strategy)

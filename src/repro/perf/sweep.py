@@ -498,7 +498,8 @@ def split_rows(rows: List[Dict], mode: str, n_fit: int = 900,
 # subject is a family-preserving ``reduced()`` of a real architecture
 # config and the distributed iteration is the *actual* LM train step
 # (``repro.train.step.make_sharded_train_step`` — registry-rule param
-# shards, in-body all-gather, wire-compressed gradient all-reduce), not
+# shards, tensor-parallel slices and per-layer streamed gathers,
+# wire-compressed gradient all-reduce), not
 # the LeNet-specific shard_map body. Intrinsics per family come from the
 # ``repro.perf.features`` registry; extrinsics are shared with LeNet.
 
@@ -607,7 +608,7 @@ def measure_sharded_arch_trial(point: ArchPoint, cfg, tcfg, mode: str, *,
     shardings = sharded_state_shardings(cfg, tcfg, mesh, point.strategy,
                                         specs)
     step_raw = make_sharded_train_step(cfg, tcfg, mesh, point.strategy,
-                                       state_specs=specs, overlap=True)
+                                       state_specs=specs)
     key = jax.random.PRNGKey(seed)
     state = init_sharded_train_state(key, cfg, tcfg, mesh)
     batch = make_batch_for(cfg, point.batch_size, point.seq_len, seed=seed)

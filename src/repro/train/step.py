@@ -1,6 +1,6 @@
 """Train step: loss → (micro-batched) grads → compression → clip → update.
 
-Two execution paths share the same TrainState and numerics:
+Two execution paths share the same TrainState, numerics and update tail:
 
 * ``make_train_step`` — the GSPMD path: a pure function for ``jax.jit``
   with explicit in/out shardings; all distribution is expressed through
@@ -9,13 +9,16 @@ Two execution paths share the same TrainState and numerics:
 
 * ``make_sharded_train_step`` — the manual-collectives path: the same
   step expressed with ``shard_map``, where every collective is written
-  out explicitly so it can be *measured* and *compressed*. Parameters
-  enter sharded per the strategy's PartitionSpecs, are all-gathered
-  in-body, per-device gradients are all-reduce-meaned over the batch
-  axes with ``repro.dist.compression.compressed_psum_mean`` (the wire-
-  compressed collective), and each device slices its shard back out and
-  applies the optimizer locally. This is the path the measured sweep
-  (docs/METHODOLOGY.md) times against the α-β simulation.
+  out explicitly so it can be *measured* and *compressed*. Each
+  parameter leaf follows its plan (``_leaf_plans``): model-sharded dims
+  the layer code can split stay local (Megatron tensor parallelism),
+  other sharded dims of scanned layer stacks are gathered per layer
+  inside the scan, and the rest are all-gathered before the loss.
+  Gradients are all-reduce-meaned over the batch axes through
+  ``repro.dist.compression.compressed_psum_mean`` (the wire-compressed
+  collective), and each device applies the optimizer to its own shard.
+  This is the path the measured sweep (docs/METHODOLOGY.md) times
+  against the α-β simulation.
 """
 from __future__ import annotations
 
@@ -97,6 +100,19 @@ def _loss_and_grads(grad_fn, params, batch, microbatches: int):
     return losses.mean(), jax.tree.map(lambda x: x.mean(), mstack), grads_acc
 
 
+def _apply_update(params, grads, opt_state, gnorm, metrics, loss,
+                  tcfg: TrainConfig, opt_update):
+    """Schedule, optimizer update and metrics merge of a clipped step:
+    ``(new_params, new_opt, metrics)``."""
+    lr = warmup_cosine(opt_state.step, peak_lr=tcfg.learning_rate,
+                       warmup_steps=tcfg.warmup_steps,
+                       total_steps=tcfg.total_steps)
+    new_params, new_opt = opt_update(params, grads, opt_state, tcfg, lr)
+    metrics = dict(metrics)
+    metrics.update(grad_norm=gnorm, lr=lr, loss=loss)
+    return new_params, new_opt, metrics
+
+
 def make_train_step(cfg: ModelConfig, tcfg: TrainConfig,
                     microbatches: int = 1):
     """Returns train_step(state, batch) -> (state, metrics)."""
@@ -118,13 +134,9 @@ def make_train_step(cfg: ModelConfig, tcfg: TrainConfig,
                                               state.ef)
 
             grads, gnorm = clip_by_global_norm(grads, tcfg.grad_clip)
-            lr = warmup_cosine(state.opt.step, peak_lr=tcfg.learning_rate,
-                               warmup_steps=tcfg.warmup_steps,
-                               total_steps=tcfg.total_steps)
-            new_params, new_opt = opt_update(params, grads, state.opt, tcfg,
-                                             lr)
-        metrics = dict(metrics)
-        metrics.update(grad_norm=gnorm, lr=lr, loss=loss)
+            new_params, new_opt, metrics = _apply_update(
+                params, grads, state.opt, gnorm, metrics, loss, tcfg,
+                opt_update)
         return TrainState(new_params, new_opt, new_ef), metrics
 
     return train_step
@@ -184,7 +196,9 @@ def sharded_state_specs(cfg: ModelConfig, tcfg: TrainConfig, mesh: Mesh,
 
     Params/opt-moments follow the strategy's logical-rule pspecs; the
     optimizer step scalar is replicated; EF buffers shard their leading
-    per-rank dimension over the batch axes and are otherwise replicated.
+    per-rank dimension over the batch axes and are sharded like the
+    gradient they correct: over ``model`` on each dim the leaf plan keeps
+    local (``LocalDim``), replicated elsewhere.
     """
     strat = resolve_strategy(strategy)
     state_shapes = jax.eval_shape(
@@ -198,8 +212,16 @@ def sharded_state_specs(cfg: ModelConfig, tcfg: TrainConfig, mesh: Mesh,
     opt_specs = OptState(P(), pspecs(opt.mu), pspecs(opt.nu))
     ef_specs = None
     if tcfg.grad_compression == "int8_ef":
-        ef_specs = jax.tree.map(lambda p: P(_batch_entry(mesh)),
-                                state_shapes.params, is_leaf=is_param)
+        def ef_spec(pl):
+            d = [a.axis if isinstance(a, LocalDim) else None
+                 for a in pl.axes]
+            while d and d[-1] is None:
+                d.pop()
+            return P(_batch_entry(mesh), *d)
+
+        ef_specs = _zip_params(
+            lambda p, pl: ef_spec(pl), state_shapes.params,
+            _leaf_plans(cfg, tcfg, mesh, p_specs, state_shapes.params))
     return TrainState(p_specs, opt_specs, ef_specs)
 
 
@@ -222,24 +244,27 @@ def sharded_batch_ok(mesh, global_batch: int) -> bool:
 
 
 class _LeafPlan(NamedTuple):
-    """Per-leaf decision for the overlap (partitioned/streamed) body."""
+    """Per-leaf decision for the shard_map body."""
     axes: Tuple        # rewritten axes tuple with LocalDim/StreamDim markers
     gather: P          # eager-gather spec (entries only on eager dims)
     streamed: bool     # any StreamDim -> grads arrive pre-reduced + sliced
     repl: float        # replication of this leaf's grad at clip time
 
 
-def _streamable_tree(cfg: ModelConfig, param_shapes):
+def _streamable_tree(cfg: ModelConfig, tcfg: TrainConfig, param_shapes):
     """Bool-at-Param-positions tree: True where per-layer streaming is safe.
 
     Only scanned segment stacks stream (their gathers then sit *inside*
     the layer scan, interleaved with compute). Zamba groups share weights
     across a nested inner scan and encoder-decoder models read segment
     weights outside the marker-aware paths (``_stacked_cross_kv``), so
-    both keep eager whole-tree gathers.
+    both keep eager whole-tree gathers. Under ``int8_ef`` nothing
+    streams: the residual is state, which cannot pass through
+    ``stream_gather``'s backward, so every leaf reduces (and keeps its
+    residual) after the loss.
     """
     flags = jax.tree.map(lambda p: False, param_shapes, is_leaf=is_param)
-    if cfg.is_encoder_decoder:
+    if cfg.is_encoder_decoder or tcfg.grad_compression == "int8_ef":
         return flags
     for i, seg in enumerate(MD.build_segments(cfg)):
         if seg.kind == "zamba_group":
@@ -249,8 +274,9 @@ def _streamable_tree(cfg: ModelConfig, param_shapes):
     return flags
 
 
-def _overlap_plans(cfg: ModelConfig, tcfg: TrainConfig, mesh: Mesh, p_specs):
-    """Classify every parameter dim for the overlap body.
+def _leaf_plans(cfg: ModelConfig, tcfg: TrainConfig, mesh: Mesh, p_specs,
+                shapes):
+    """Classify every parameter dim for the shard_map body.
 
     Per sharded dim, in priority order:
 
@@ -260,8 +286,8 @@ def _overlap_plans(cfg: ModelConfig, tcfg: TrainConfig, mesh: Mesh, p_specs):
     * **streamed** (``StreamDim``) — any other sharded dim of a leaf in a
       scanned segment stack: left sharded, all-gathered per layer inside
       the scan, gradient reduce-scattered by ``stream_gather``'s backward;
-    * **eager** — everything else keeps the legacy whole-array gather
-      (top-level leaves: embedding, final norm, lm_head, mtp).
+    * **eager** — everything else is all-gathered whole before the
+      loss (top-level leaves: embedding, final norm, lm_head, mtp).
 
     ``repl`` counts how many ranks hold each element of the leaf's
     *reduced* gradient at clip time: eager dims are gathered full
@@ -273,9 +299,7 @@ def _overlap_plans(cfg: ModelConfig, tcfg: TrainConfig, mesh: Mesh, p_specs):
     for s in sizes.values():
         n_total *= s
     live = MD.tp_live_axes(cfg, sizes.get("model", 1))
-    shapes = jax.eval_shape(
-        lambda: init_train_state(jax.random.PRNGKey(0), cfg, tcfg)).params
-    streamable = _streamable_tree(cfg, shapes)
+    streamable = _streamable_tree(cfg, tcfg, shapes)
 
     def one(p, spec, can_stream):
         nd = len(p.axes)
@@ -315,15 +339,15 @@ def _overlap_plans(cfg: ModelConfig, tcfg: TrainConfig, mesh: Mesh, p_specs):
 def overlap_transient_bytes(cfg: ModelConfig, tcfg: TrainConfig, mesh,
                             strategy="dp", state_specs=None
                             ) -> Tuple[int, int]:
-    """(eager_bytes, stream_chunk_bytes) the overlap body's gathers add
-    per device beyond the persistent parameter shards.
+    """(eager_bytes, stream_chunk_bytes) the shard_map body's gathers
+    add per device beyond the persistent parameter shards.
 
     Eager leaves (embedding, lm_head, norms, zamba groups, enc-dec
     segments) hold their whole gathered array for the step; streamed
     segment stacks materialize at most one layer's gathered slice at a
     time inside the scan, so their term is the largest single-layer
     chunk across segments — the number the planner's memory model
-    charges instead of the legacy full-tree transient (docs/PLANNER.md).
+    charges instead of the whole-tree transient (docs/PLANNER.md).
     Partitioned (``LocalDim``) dims are never gathered and contribute to
     neither term. ``mesh`` may be a Mesh or a plain ``{axis: size}``
     mapping (the planner prices candidate meshes without devices).
@@ -331,9 +355,9 @@ def overlap_transient_bytes(cfg: ModelConfig, tcfg: TrainConfig, mesh,
     strat = resolve_strategy(strategy)
     if state_specs is None:
         state_specs = sharded_state_specs(cfg, tcfg, mesh, strat)
-    plans = _overlap_plans(cfg, tcfg, mesh, state_specs.params)
     shapes = jax.eval_shape(
         lambda: init_train_state(jax.random.PRNGKey(0), cfg, tcfg)).params
+    plans = _leaf_plans(cfg, tcfg, mesh, state_specs.params, shapes)
     sizes = axis_sizes(mesh)
 
     def one(p, pl):
@@ -378,38 +402,35 @@ def overlap_transient_bytes(cfg: ModelConfig, tcfg: TrainConfig, mesh,
 
 def make_sharded_train_step(cfg: ModelConfig, tcfg: TrainConfig, mesh: Mesh,
                             strategy="dp", microbatches: int = 1,
-                            state_specs: Optional[TrainState] = None,
-                            overlap: bool = False):
+                            state_specs: Optional[TrainState] = None):
     """The measured multi-device path: shard_map with explicit collectives.
 
-    Per step, on each device:
+    ``_leaf_plans`` rewrites each parameter's axes with ``LocalDim``
+    (Megatron tensor-parallel slice over ``model`` — column/row split
+    MLPs, local attention heads, expert-local MoE) and ``StreamDim``
+    (ZeRO-style per-layer gather inside the layer scan) markers. Per
+    step, on each device:
 
-      1. all-gather this device's parameter shards up to full arrays
-         (``gather_to_full`` inverts each param's PartitionSpec — for
-         ``dp`` params are replicated and no gather is emitted);
+      1. all-gather the *eager* dims of each parameter shard: those of
+         leaves outside the scanned stacks, and under ``int8_ef`` every
+         dim not kept local. Local dims stay sliced; streamed dims are
+         gathered layer by layer while the loss runs, interleaved with
+         compute;
       2. compute gradients of the *local* sub-batch (micro-batched if
-         asked);
+         asked) on the model rank's slice of the tensor-parallel work;
       3. all-reduce-mean the gradients over the batch axes through the
          compressed collective (``compressed_psum_mean`` /
          ``compressed_psum_mean_ef`` for int8 error feedback — the
-         residual stays on this device);
-      4. clip by the global norm of the full reduced gradient (identical
-         on every rank after the psum), slice each gradient back to this
-         device's shard, and apply the optimizer update locally — the
-         update is elementwise, so sharded params/moments stay sharded.
+         residual stays on this device, sharded like the gradient). A
+         streamed leaf's gradient arrives already reduced and sliced by
+         ``stream_gather``'s backward;
+      4. clip by the global norm of the full gradient (a partition-aware
+         sum of squares, one psum over the mesh), slice each gradient
+         back to this device's shard, and apply the optimizer update
+         locally — the update is elementwise, so sharded params/moments
+         stay sharded.
 
-    With ``overlap=False`` (legacy) the batch is replicated over the
-    ``model`` axis: every model rank computes identical full gradients
-    and only the memory layout (and its gather traffic) differs per
-    strategy. With ``overlap=True`` the step truly partitions compute:
-    ``_overlap_plans`` rewrites each parameter's axes with ``LocalDim``
-    (Megatron tensor-parallel slice over ``model`` — column/row split
-    MLPs, local attention heads, expert-local MoE) and ``StreamDim``
-    (ZeRO-style per-layer streamed gather inside the layer scan, with
-    the gradient reduce-scatter fused into ``stream_gather``'s backward)
-    markers, so parameter gathers and gradient reductions interleave
-    with per-layer compute instead of serializing around the loss — see
-    docs/DIST.md ("Partitioned tp body and streaming gathers").
+    See docs/DIST.md ("Partitioned tp body and streaming gathers").
 
     Restrictions: optimizer must be elementwise (adamw/sgd — adafactor's
     factored moments take row/col means over dims this path shards), the
@@ -433,84 +454,19 @@ def make_sharded_train_step(cfg: ModelConfig, tcfg: TrainConfig, mesh: Mesh,
     if state_specs is None:     # deriving specs traces the full init
         state_specs = sharded_state_specs(cfg, tcfg, mesh, strat)
     p_specs = state_specs.params
+    shapes = jax.eval_shape(
+        lambda: init_train_state(jax.random.PRNGKey(0), cfg, tcfg)).params
+    plans = _leaf_plans(cfg, tcfg, mesh, p_specs, shapes)
+    sizes = axis_sizes(mesh)
+    mesh_axes = tuple(sizes)
+    sorted_sizes = tuple(sorted(sizes.items()))
 
     def body(state: TrainState, batch):
         # jax.named_scope labels are trace-time only: they name the HLO
         # regions after the cost model's terms (visible in jax.profiler /
         # Perfetto) and cost nothing in the compiled program.
-        with manual_mode():
-            params = state.params
-            with jax.named_scope("obs:gather_params"):
-                full_params = _zip_params(
-                    lambda p, s: Param(gather_to_full(p.value, s), p.axes),
-                    params, p_specs)
-            with jax.named_scope("obs:grad_compute"):
-                loss, metrics, grads = _loss_and_grads(
-                    grad_fn, full_params, batch, microbatches)
-            gvals = pvalues(grads) if microbatches <= 1 else grads
-
-            new_ef = state.ef
-            with jax.named_scope("obs:grad_reduce"):
-                if mode == "int8_ef":
-                    # pairs holds (mean, new_err) tuples at Param
-                    # positions; always unzip against the params treedef
-                    # so the tuples are never mistaken for pytree
-                    # internals.
-                    pairs = _zip_params(
-                        lambda p, g, e: compressed_psum_mean_ef(
-                            g.astype(jnp.float32), batch_axes, e.value[0]),
-                        params, gvals, state.ef)
-                    reduced = _zip_params(lambda p, t: t[0], params, pairs)
-                    new_ef = _zip_params(
-                        lambda p, t, e: Param(t[1][None], e.axes),
-                        params, pairs, state.ef)
-                else:
-                    reduced = jax.tree.map(
-                        lambda g: compressed_psum_mean(
-                            g.astype(jnp.float32), batch_axes, mode),
-                        gvals)
-                loss = jax.lax.pmean(loss, batch_axes)
-                metrics = jax.tree.map(
-                    lambda m: jax.lax.pmean(m, batch_axes), metrics)
-
-            # the wire compression is the collective's (obs:grad_reduce)
-            with jax.named_scope("obs:update"), \
-                    jax.named_scope("optimizer"):
-                reduced, gnorm = clip_by_global_norm(reduced,
-                                                     tcfg.grad_clip)
-                grads_shard = _zip_params(
-                    lambda g, s, p: Param(shard_of_full(g, s, mesh),
-                                          p.axes),
-                    reduced, p_specs, params)
-                lr = warmup_cosine(state.opt.step,
-                                   peak_lr=tcfg.learning_rate,
-                                   warmup_steps=tcfg.warmup_steps,
-                                   total_steps=tcfg.total_steps)
-                new_params, new_opt = opt_update(params, grads_shard,
-                                                 state.opt, tcfg, lr)
-            metrics = dict(metrics)
-            metrics.update(grad_norm=gnorm, lr=lr, loss=loss)
-            return TrainState(new_params, new_opt, new_ef), metrics
-
-    if not overlap:
-        return jax.shard_map(body, mesh=mesh,
-                             in_specs=(state_specs, P(_batch_entry(mesh))),
-                             out_specs=(state_specs, P()),
-                             check_vma=False)
-
-    plans = _overlap_plans(cfg, tcfg, mesh, p_specs)
-    sizes = axis_sizes(mesh)
-    mesh_axes = tuple(sizes)
-    sorted_sizes = tuple(sorted(sizes.items()))
-    # Streamed leaves reduce on the wire inside stream_gather's backward;
-    # error feedback is stateful and cannot thread through a vjp, so the
-    # int8_ef wire degrades to plain int8 for those leaves (identical for
-    # a fresh state — the residual starts at zero).
-    stream_mode = "int8" if mode == "int8_ef" else mode
-
-    def overlap_body(state: TrainState, batch):
         with manual_mode(), MD.stream_context(sorted_sizes, batch_axes,
-                                              stream_mode):
+                                              mode):
             params = state.params
             with jax.named_scope("obs:gather_params"):
                 # eager gathers only — streamed/partitioned leaves gather
@@ -527,17 +483,17 @@ def make_sharded_train_step(cfg: ModelConfig, tcfg: TrainConfig, mesh: Mesh,
             new_ef = state.ef
             with jax.named_scope("obs:grad_reduce"):
                 if mode == "int8_ef":
+                    # pairs holds (mean, new_err) tuples at Param
+                    # positions; always unzip against the params treedef
+                    # so the tuples are never mistaken for pytree
+                    # internals. Nothing streams under int8_ef.
                     pairs = _zip_params(
-                        lambda p, g, e, pl: (
-                            (g.astype(jnp.float32), None) if pl.streamed
-                            else compressed_psum_mean_ef(
-                                g.astype(jnp.float32), batch_axes,
-                                e.value[0])),
-                        params, gvals, state.ef, plans)
+                        lambda p, g, e: compressed_psum_mean_ef(
+                            g.astype(jnp.float32), batch_axes, e.value[0]),
+                        params, gvals, state.ef)
                     reduced = _zip_params(lambda p, t: t[0], params, pairs)
                     new_ef = _zip_params(
-                        lambda p, t, e: (e if t[1] is None
-                                         else Param(t[1][None], e.axes)),
+                        lambda p, t, e: Param(t[1][None], e.axes),
                         params, pairs, state.ef)
                 else:
                     reduced = _zip_params(
@@ -574,17 +530,12 @@ def make_sharded_train_step(cfg: ModelConfig, tcfg: TrainConfig, mesh: Mesh,
                     lambda p, g, pl: Param(
                         shard_of_full(g, pl.gather, mesh), p.axes),
                     params, clipped, plans)
-                lr = warmup_cosine(state.opt.step,
-                                   peak_lr=tcfg.learning_rate,
-                                   warmup_steps=tcfg.warmup_steps,
-                                   total_steps=tcfg.total_steps)
-                new_params, new_opt = opt_update(params, grads_shard,
-                                                 state.opt, tcfg, lr)
-            metrics = dict(metrics)
-            metrics.update(grad_norm=gnorm, lr=lr, loss=loss)
+                new_params, new_opt, metrics = _apply_update(
+                    params, grads_shard, state.opt, gnorm, metrics, loss,
+                    tcfg, opt_update)
             return TrainState(new_params, new_opt, new_ef), metrics
 
-    return jax.shard_map(overlap_body, mesh=mesh,
+    return jax.shard_map(body, mesh=mesh,
                          in_specs=(state_specs, P(_batch_entry(mesh))),
                          out_specs=(state_specs, P()),
                          check_vma=False)

@@ -1,30 +1,33 @@
-"""Partitioned/overlap shard_map step vs single-device reference.
+"""Partitioned shard_map step vs single-device reference.
 
-The tentpole path (``make_sharded_train_step(..., overlap=True)``) runs
-real tensor-parallel compute: Megatron column/row-split MLPs, local
+The shard_map step (``make_sharded_train_step``) runs real
+tensor-parallel compute: Megatron column/row-split MLPs, local
 attention heads, expert-local MoE stacks, and per-layer streamed fsdp
 gathers inside the scan. This file pins its *numerics* family by
 family — lm, ssm, moe, lenet — against the single-device full-batch
 gradient, with the same tiered tolerances as tests/test_sharded_step.py
-(which covers the legacy eager-gather body):
+(which covers every strategy × wire format on a one-layer model):
 
 * "none"    — fp32 reduction-ordering noise only (floor 2e-5: the
   partitioned path re-associates matmul reductions across ranks).
   The gradient is read back as (p0 - p1) / lr, which resolves it only
   to half an f32 ulp of the parameter over lr. At lr 1e-2 that was
   2.4e-5 for mamba2's dt_bias (|p| up to 6.9) — above the floor, on
-  every mesh and on the legacy body alike, while the single-device
+  every mesh alike, while the single-device
   fp32 gradient agrees with float64 to 2e-6. So the arch cases take
   one sgd step at lr 1, where the readback resolves 2.4e-7.
 * "int8"    — one shared-scale int8 ulp of the per-shard grad maxima.
 * "int8_ef" — same bound step-1; the residual buffer must engage.
 
+A case may name a micro-batch count as its third field (the launcher's
+``--microbatches``); the gradient is then the mean over micro-batches.
+
 MoE is the one family where batch sharding changes the math (capacity
 is computed from *local* tokens and the aux loss is nonlinear in the
 router probabilities): a pure-model mesh (data=1) is exact vs single
-device, while fsdp_tp is pinned overlap-vs-legacy — same mesh, same
-sharded semantics, so the partitioned compute must reproduce the
-eager-gather body's update.
+device, while fsdp_tp is pinned against the mean over the data shards
+of the single-device gradient on each shard's rows — the sharded
+semantics, so the partitioned compute must reproduce it.
 
 Runs in subprocesses so the 8-device placeholder pool does not leak
 into the rest of the session.
@@ -91,7 +94,8 @@ def tol_for(mode, j, g):
 
 mesh = make_mesh((DATA, 8 // DATA), ("data", "model"))
 results = {}
-for strategy, comp in CASES:
+for strategy, comp, *mb in CASES:
+    mb = mb[0] if mb else 1
     tcfg = TrainConfig(learning_rate=LR, optimizer="sgd", beta1=0.0,
                        weight_decay=0.0, grad_clip=1e9, total_steps=10,
                        warmup_steps=0, remat_policy="none",
@@ -100,7 +104,7 @@ for strategy, comp in CASES:
     sh = sharded_state_shardings(cfg, tcfg, mesh, strategy)
     state = jax.device_put(state, sh)
     step = jax.jit(make_sharded_train_step(cfg, tcfg, mesh, strategy,
-                                           overlap=True),
+                                           microbatches=mb),
                    in_shardings=(sh, None), out_shardings=(sh, None))
     new_state, metrics = step(state, batch)
     lr0 = float(metrics["lr"])
@@ -113,13 +117,13 @@ for strategy, comp in CASES:
         got = (a - b) / lr0
         err = float(np.max(np.abs(got - g)))
         lim = tol_for(comp, j, g)
-        assert err <= lim, (strategy, comp, j, err, lim)
+        assert err <= lim, (strategy, comp, mb, j, err, lim)
         worst = max(worst, err / lim)
     if comp == "int8_ef":
         ef = jax.tree.leaves(pvalues(new_state.ef))
         assert sum(float(np.sum(np.abs(np.asarray(e)))) for e in ef) > 0, \
             "error feedback never engaged"
-    results[f"{strategy}/{comp}"] = worst
+    results[f"{strategy}/{comp}" + (f"/mb{mb}" if mb > 1 else "")] = worst
 print(json.dumps({"ok": True, "worst_frac_of_tol": results}))
 """
 
@@ -131,16 +135,17 @@ def _arch_snippet(arch, red, data, cases):
 
 
 def test_lm_partitioned_tp_matches_single_device():
-    """smollm (dense lm): tp/fsdp_tp overlap bodies reproduce the
-    full-batch gradient under none and int8 wire formats; int8_ef's
-    residual engages."""
+    """smollm (dense lm): tp/fsdp_tp reproduce the full-batch gradient
+    under none and int8 wire formats, and with two micro-batches;
+    int8_ef's residual engages, on local tensor-parallel slices too."""
     r = _run(_arch_snippet(
         "smollm-360m", dict(n_layers=2, d_model=32, vocab=128, d_ff=64),
         4, [("tp", "none"), ("fsdp_tp", "none"),
-            ("tp", "int8"), ("fsdp_tp", "int8"), ("fsdp_tp", "int8_ef")]))
+            ("tp", "int8"), ("fsdp_tp", "int8"), ("tp", "int8_ef"),
+            ("fsdp_tp", "int8_ef"), ("fsdp_tp", "none", 2)]))
     assert r.returncode == 0, (r.stdout[-2000:], r.stderr[-3000:])
     out = json.loads(r.stdout.strip().splitlines()[-1])
-    assert out["ok"] and len(out["worst_frac_of_tol"]) == 5
+    assert out["ok"] and len(out["worst_frac_of_tol"]) == 7
 
 
 def test_ssm_partitioned_tp_matches_single_device():
@@ -166,23 +171,40 @@ def test_moe_expert_parallel_tp_matches_single_device():
     assert out["ok"] and len(out["worst_frac_of_tol"]) == 2
 
 
-MOE_OVERLAP_VS_LEGACY = r"""
+MOE_MEAN_OF_SHARDS = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import dataclasses, json
-import jax, jax.numpy as jnp, numpy as np
+import jax, numpy as np
 from repro.configs import TrainConfig, get_config, reduced
 from repro.data import make_batch_for
 from repro.launch.mesh import make_mesh
+from repro.models import model as MD
 from repro.models.layers import pvalues
 from repro.train import (init_sharded_train_state, make_sharded_train_step,
                          sharded_state_shardings)
 
 cfg = reduced(get_config("llama4-scout-17b-a16e"))
 cfg = dataclasses.replace(cfg, dtype="float32", param_dtype="float32")
-LR, B, S = 1e-2, 8, 32
+LR, B, S, DATA = 1.0, 8, 32, 4
 batch = make_batch_for(cfg, B, S, step=0)
-mesh = make_mesh((4, 2), ("data", "model"))
+
+# the sharded semantics on one device: each data shard's gradient on its
+# own rows (capacity and aux loss from local tokens), meaned
+ref_params = MD.init_model(jax.random.PRNGKey(0), cfg)
+grad_of = jax.jit(jax.value_and_grad(
+    lambda p, b: MD.loss_fn(p, cfg, b), has_aux=True))
+ref_leaves = None
+for i in range(DATA):
+    sub = jax.tree.map(lambda x: x[i * (B // DATA):(i + 1) * (B // DATA)],
+                       batch)
+    (_, _), g = grad_of(ref_params, sub)
+    leaves = [np.asarray(x, np.float32) / DATA
+              for x in jax.tree.leaves(pvalues(g))]
+    ref_leaves = leaves if ref_leaves is None else [
+        a + b for a, b in zip(ref_leaves, leaves)]
+
+mesh = make_mesh((DATA, 8 // DATA), ("data", "model"))
 tcfg = TrainConfig(learning_rate=LR, optimizer="sgd", beta1=0.0,
                    weight_decay=0.0, grad_clip=1e9, total_steps=10,
                    warmup_steps=0, remat_policy="none",
@@ -190,35 +212,30 @@ tcfg = TrainConfig(learning_rate=LR, optimizer="sgd", beta1=0.0,
 state = init_sharded_train_state(jax.random.PRNGKey(0), cfg, tcfg, mesh)
 sh = sharded_state_shardings(cfg, tcfg, mesh, "fsdp_tp")
 state = jax.device_put(state, sh)
-outs = {}
-for overlap in (False, True):
-    step = jax.jit(make_sharded_train_step(cfg, tcfg, mesh, "fsdp_tp",
-                                           overlap=overlap),
-                   in_shardings=(sh, None), out_shardings=(sh, None))
-    new_state, metrics = step(state, batch)
-    outs[overlap] = ([np.asarray(x, np.float32) for x in
-                      jax.tree.leaves(pvalues(new_state.params))],
-                     float(metrics["lr"]))
+step = jax.jit(make_sharded_train_step(cfg, tcfg, mesh, "fsdp_tp"),
+               in_shardings=(sh, None), out_shardings=(sh, None))
+new_state, metrics = step(state, batch)
+lr0 = float(metrics["lr"])
 p0 = [np.asarray(x, np.float32)
       for x in jax.tree.leaves(pvalues(state.params))]
+p1 = [np.asarray(x, np.float32)
+      for x in jax.tree.leaves(pvalues(new_state.params))]
 worst = 0.0
-for a, (legacy, ov) in zip(p0, zip(outs[False][0], outs[True][0])):
-    g_leg = (a - legacy) / outs[False][1]
-    g_ov = (a - ov) / outs[True][1]
-    err = float(np.max(np.abs(g_ov - g_leg)))
-    lim = 2e-5 + 1e-5 * float(np.max(np.abs(g_leg)))
-    assert err <= lim, (err, lim)
+for j, (a, b, g) in enumerate(zip(p0, p1, ref_leaves)):
+    err = float(np.max(np.abs((a - b) / lr0 - g)))
+    lim = 2e-5 + 1e-5 * float(np.max(np.abs(g)))
+    assert err <= lim, (j, err, lim)
     worst = max(worst, err / lim)
 print(json.dumps({"ok": True, "worst_frac_of_tol": worst}))
 """
 
 
-def test_moe_fsdp_tp_overlap_matches_legacy_body():
+def test_moe_fsdp_tp_matches_mean_of_shard_gradients():
     """fsdp_tp shards the batch, which legitimately changes MoE capacity
-    vs single device — so pin the partitioned body against the legacy
-    eager-gather body on the *same* mesh: identical sharded semantics,
-    the gradients must agree to fp32 ordering noise."""
-    r = _run(MOE_OVERLAP_VS_LEGACY)
+    vs single device — so pin the partitioned body against the mean over
+    the data shards of the single-device fp32 gradient on each shard's
+    rows: the sharded semantics, to fp32 ordering noise."""
+    r = _run(MOE_MEAN_OF_SHARDS)
     assert r.returncode == 0, (r.stdout[-2000:], r.stderr[-3000:])
     assert json.loads(r.stdout.strip().splitlines()[-1])["ok"]
 

@@ -1,12 +1,14 @@
 """Sharded (shard_map) train step vs single-device reference.
 
 The measured path must be *numerically equivalent* to the single-device
-step, strategy by strategy: gathering parameter shards, computing
-per-device gradients on batch shards, and all-reduce-meaning them
-through the compressed collective has to reproduce the full-batch
-gradient within the wire format's quantization bound. Tolerances are
-tiered: exact-ish for fp32 ("none"), one bf16 ulp for "bf16", one
-shared-scale int8 ulp for "int8"/"int8_ef".
+step, strategy by strategy: computing on tensor-parallel slices,
+gathering the other parameter shards, computing per-device gradients on
+batch shards, and all-reduce-meaning them through the compressed
+collective has to reproduce the full-batch gradient within the wire
+format's quantization bound. Tolerances are tiered: exact-ish for fp32
+("none"), one bf16 ulp for "bf16", one shared-scale int8 ulp for
+"int8"/"int8_ef". Under ``tp`` the int8_ef residual of a tensor-parallel
+leaf holds only the local slice, sharded like its gradient.
 
 Runs in a subprocess so the 8-device placeholder pool does not leak into
 the rest of the session (same pattern as tests/test_system.py).
@@ -81,7 +83,7 @@ mesh = make_mesh((4, 2), ("data", "model"))
 results = {}
 cases = [(s, "none") for s in ("dp", "fsdp", "tp", "fsdp_tp")]
 cases += [(s, "int8") for s in ("dp", "fsdp", "tp", "fsdp_tp")]
-cases += [("dp", "bf16"), ("fsdp_tp", "int8_ef")]
+cases += [("dp", "bf16"), ("tp", "int8_ef"), ("fsdp_tp", "int8_ef")]
 for strategy, comp in cases:
     # sgd with wd=0, momentum disabled via b1=0 and huge clip turns the
     # one-step param delta into the post-collective mean gradient:
@@ -130,7 +132,7 @@ def test_sharded_grads_match_single_device_per_strategy():
     out = json.loads(r.stdout.strip().splitlines()[-1])
     assert out["ok"]
     # every case stayed within its tier (sanity: dict fully populated)
-    assert len(out["worst_frac_of_tol"]) == 10
+    assert len(out["worst_frac_of_tol"]) == 11
 
 
 EF_HORIZON_SNIPPET = r"""

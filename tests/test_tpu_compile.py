@@ -7,7 +7,8 @@ VMEM. Here each kernel is lowered and compiled for one chip of a
 described (not attached) v5e:2x2, so a layout the compiler refuses
 fails a test instead of a chip run, and each is checked to carry its
 kernel name into the compiled custom-call. A cut train step checks
-that the program's layer scopes reach the ops the chip runs. Nothing
+that the program's layer scopes reach the ops the chip runs, and the
+shard_map step compiles for all four chips of the 2x2. Nothing
 executes.
 
 The topology is described inside a fixture, never at import: only one
@@ -30,7 +31,8 @@ from repro.models.attention import AttnSpec
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def v5e():
+    """The described v5e:2x2 topology."""
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
@@ -45,8 +47,13 @@ def one_chip():
     except Exception as e:
         jax.config.update("jax_enable_compilation_cache", was)
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", was)
+
+
+@pytest.fixture(scope="module")
+def one_chip(v5e):
+    return SingleDeviceSharding(v5e.devices[0])
 
 
 def _compiled_text(fn, *shapes):
@@ -284,3 +291,46 @@ def test_mamba2_step_scan_carries_its_scope(one_chip, monkeypatch):
     assert {re.sub(r"\.\d+$", "", k) for k in bwd} == {
         "ssd_scan_bwd_states", "ssd_scan_bwd_grads"}
     assert {keys[k] for k in bwd} == {("ssd_scan", "bwd")}
+
+
+def test_sharded_step_compiles_for_four_chips(v5e, monkeypatch):
+    """smollm-360m at published widths, cut to 2 layers, as the shard_map
+    step trains it on a (data 2, model 2) mesh of the v5e:2x2's four
+    chips: fsdp_tp, bf16, remat full, batch 4 x 2048. The compiled step
+    carries the flash forward and the backward's three kernels, and each
+    model rank's MLP hidden holds half of d_ff (2560 / 2)."""
+    import dataclasses
+    from repro.configs import TrainConfig, get_config
+    from repro.kernels import ops
+    from repro.launch.mesh import make_mesh
+    from repro.launch.specs import batch_shardings
+    from repro.models.layers import tp_probe_sink
+    from repro.train.step import (init_sharded_train_state,
+                                  make_sharded_train_step,
+                                  sharded_state_shardings,
+                                  sharded_state_specs)
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    cfg = dataclasses.replace(get_config("smollm-360m"), n_layers=2)
+    assert cfg.dtype == "bfloat16" and cfg.d_ff == 2560
+    tcfg = TrainConfig(remat_policy="full")
+    mesh = make_mesh((2, 2), ("data", "model"), devices=v5e.devices)
+    specs = sharded_state_specs(cfg, tcfg, mesh, "fsdp_tp")
+    st_shard = sharded_state_shardings(cfg, tcfg, mesh, "fsdp_tp",
+                                       specs=specs)
+    # the jit's shardings place the shapes on the described chips
+    state = jax.eval_shape(
+        lambda: init_sharded_train_state(jax.random.PRNGKey(0), cfg, tcfg,
+                                         mesh))
+    batch = {"tokens": jax.ShapeDtypeStruct((4, SEQ), jnp.int32)}
+    b_shard = batch_shardings(batch, mesh)
+    step = jax.jit(make_sharded_train_step(cfg, tcfg, mesh, "fsdp_tp",
+                                           state_specs=specs),
+                   in_shardings=(st_shard, b_shard),
+                   out_shardings=(st_shard, None))
+    with tp_probe_sink([]) as rec:
+        lowered = step.lower(state, batch)
+    hidden = {tuple(shape) for tag, shape in rec if tag == "mlp_hidden"}
+    assert hidden and all(h[-1] == 1280 for h in hidden), hidden
+    txt = lowered.compile().as_text()
+    assert _kernels(txt) == {"flash_fwd", "flash_bwd_lse", "flash_bwd_dq",
+                             "flash_bwd_dkv"}

@@ -1,31 +1,19 @@
-"""CI smoke for the comm/compute-overlap train step: partition + speed.
+"""CI smoke for the partitioned tp train step: it shards activations.
 
   PYTHONPATH=src python tools/overlap_smoke.py
 
 On the forced 8-device host pool, builds the sharded LM train step for
-``tp`` on the (data:1, model:8) mesh twice — the legacy sequential body
-(``overlap=False``: gather everything, then compute) and the
-partitioned body (``overlap=True``: Megatron column/row-split matmuls
-on local parameter slices) — and asserts the two claims the overlap
-work stands on:
-
-  1. **The tp body really shards activations over the model axis.**
-     Tracing each step under ``tp_probe_sink`` captures the local
-     ``mlp_hidden`` shape inside the shard_map body: the sequential
-     body sees the full d_ff, the overlapped body must see exactly
-     d_ff/8 on the same leading dims.
-  2. **Overlapped ≤ sequential step time.** On this mesh the legacy
-     body computes the full batch with full parameters on every model
-     rank (8× replicated flops), while the partitioned body computes a
-     1/8 slice — so even on a timeshared host pool the overlapped step
-     is strictly faster. Timed as min of ``ITERS`` compiled steps (the
-     min estimator rejects the pool's one-sided scheduler noise).
+``tp`` on the (data:1, model:8) mesh and traces it under
+``tp_probe_sink``, which captures the local ``mlp_hidden`` shapes inside
+the shard_map body. The Megatron column/row split must leave each model
+rank exactly ``d_ff / 8`` of the hidden width: every recorded shape has
+to end in ``cfg.d_ff // 8``.
 
 Numerical parity of the partitioned body is pinned family-by-family in
 ``tests/test_overlap_parity.py``; this smoke guards the *structural*
 claim cheaply on every push.
 
-Exit code 0 = both hold; anything else fails CI.
+Exit code 0 = it holds; anything else fails CI.
 """
 import os
 import sys
@@ -43,7 +31,7 @@ import json         # noqa: E402
 import time         # noqa: E402
 
 ARCH, STRATEGY = "smollm-360m", "tp"
-B, S, ITERS = 8, 32, 5
+B, S = 8, 32
 
 
 def main():
@@ -73,57 +61,23 @@ def main():
     state = init_sharded_train_state(jax.random.PRNGKey(0), cfg, tcfg, mesh)
     sh = sharded_state_shardings(cfg, tcfg, mesh, STRATEGY)
     state = jax.device_put(state, sh)
+    step = jax.jit(make_sharded_train_step(cfg, tcfg, mesh, STRATEGY),
+                   in_shardings=(sh, None), out_shardings=(sh, None))
 
-    def build(overlap):
-        return jax.jit(make_sharded_train_step(cfg, tcfg, mesh, STRATEGY,
-                                               overlap=overlap),
-                       in_shardings=(sh, None), out_shardings=(sh, None))
+    with tp_probe_sink([]) as rec:
+        step.lower(state, batch)
+    local = sorted({tuple(shape) for tag, shape in rec
+                    if tag == "mlp_hidden"})
 
-    def probe_shapes(step):
-        with tp_probe_sink([]) as rec:
-            step.lower(state, batch)
-        shapes = {}
-        for tag, shape in rec:
-            shapes.setdefault(tag, set()).add(tuple(shape))
-        return shapes
-
-    seq_step, ov_step = build(False), build(True)
-    seq_shapes, ov_shapes = probe_shapes(seq_step), probe_shapes(ov_step)
-
-    # -- claim 1: the overlapped body computes on model-sharded hiddens --
-    assert "mlp_hidden" in seq_shapes and "mlp_hidden" in ov_shapes, \
-        {"seq": seq_shapes, "overlap": ov_shapes}
-    for ls in ov_shapes["mlp_hidden"]:
-        want = ls[:-1] + (ls[-1] * m,)
-        assert want in seq_shapes["mlp_hidden"], {
-            "local": ls, "expected_full": want,
-            "sequential_saw": sorted(seq_shapes["mlp_hidden"])}
-
-    # -- claim 2: overlapped <= sequential wall clock -----------------
-    def step_ms(step):
-        out, _ = step(state, batch)            # warm-up / compile
-        jax.block_until_ready(out)
-        best = float("inf")
-        for _ in range(ITERS):
-            t = time.perf_counter()
-            out, _ = step(state, batch)
-            jax.block_until_ready(out)
-            best = min(best, time.perf_counter() - t)
-        return best * 1e3
-
-    seq_ms, ov_ms = step_ms(seq_step), step_ms(ov_step)
-    assert ov_ms <= seq_ms, {
-        "sequential_ms": seq_ms, "overlapped_ms": ov_ms,
-        "note": "partitioned body must not be slower than the "
-                "gather-everything body on the tp mesh"}
+    # the tp body computes on model-sharded hiddens: d_ff / m per rank
+    assert local, {"recorded": sorted({tag for tag, _ in rec})}
+    assert all(ls[-1] == cfg.d_ff // m for ls in local), {
+        "local": local, "d_ff": cfg.d_ff, "model": m}
 
     print(json.dumps({
         "ok": True, "arch": ARCH, "strategy": STRATEGY,
-        "mesh": dict(axes),
-        "mlp_hidden_full": sorted(seq_shapes["mlp_hidden"]),
-        "mlp_hidden_local": sorted(ov_shapes["mlp_hidden"]),
-        "sequential_ms": round(seq_ms, 2), "overlapped_ms": round(ov_ms, 2),
-        "ratio": round(ov_ms / seq_ms, 3),
+        "mesh": dict(axes), "d_ff": cfg.d_ff,
+        "mlp_hidden_local": local,
         "wall_s": round(time.time() - t0, 1)}))
     return 0
 
